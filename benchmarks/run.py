@@ -22,8 +22,11 @@ table size without smoke-mode shortcuts (the nightly job's 50k regime).
 
 import argparse
 import json
+import os
 import pathlib
 import time
+
+import jax
 
 from . import (
     fig6_offset_revisions,
@@ -106,6 +109,11 @@ def main() -> None:
                     help="write the report to benchmarks/baselines/ — the "
                          "committed reference the CI perf-gate compares against")
     args = ap.parse_args()
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        # a fixed in-checkout path, so a later process hits what this one
+        # compiled; JAX_COMPILATION_CACHE_DIR, when set, wins
+        jax.config.update("jax_compilation_cache_dir",
+                          str(BASELINE_DIR.parent.parent / ".jax_cache"))
     if args.smoke:
         set_smoke(True)
     if args.rows is not None:

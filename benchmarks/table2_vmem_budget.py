@@ -8,11 +8,12 @@ whether the engine's tiles double-buffer cleanly.
 """
 
 from repro.core import TableGeometry, benchmark_schema
+from repro.kernels.common import VMEM_BYTES_BY_KIND
 from repro.kernels.rme_project import vmem_footprint_bytes
 
 from .common import emit
 
-VMEM_BYTES = 128 << 20  # v5e per-core VMEM
+VMEM_BYTES = VMEM_BYTES_BY_KIND["TPU v5 lite"]
 
 
 def run() -> None:

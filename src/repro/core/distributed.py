@@ -50,7 +50,7 @@ from typing import Iterator, Sequence
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -658,12 +658,14 @@ class ShardedEngine(RelationalMemoryEngine):
         """
         faults.maybe_fault("scan_launch", table=table.uid)
         shards = self.rowstore.shard_parts(table)
-        block_rows = self._fused_block_rows(reqs, table.row_words)
+        vmem = next((self._vmem_budget(cs[0].words) for cs in shards if cs),
+                    self.vmem_bytes)
+        block_rows = self._fused_block_rows(reqs, table.row_words, vmem)
         per_shard: list[tuple[list[_ShardChunk], list[list]]] = []
         for s, chunks in enumerate(shards):
             if not chunks:
                 continue
-            outs = self._shard_pass(table, s, chunks, reqs, block_rows)
+            outs = self._shard_pass(table, s, chunks, reqs, block_rows, vmem)
             per_shard.append((chunks, outs))
             for c in chunks:
                 self.charge_scan(table, reqs, row_count=c.rows)
@@ -717,7 +719,7 @@ class ShardedEngine(RelationalMemoryEngine):
     # -------------------------------------------------- failover machinery
     def _shard_pass(self, table: RelationalTable, shard: int, chunks,
                     reqs: tuple["KR.ScanRequest", ...],
-                    block_rows: int) -> list[list]:
+                    block_rows: int, vmem: int) -> list[list]:
         """One shard's fused pass with bounded retry, failover, quarantine.
 
         A transient fault retries up to ``shard_retries`` times with
@@ -742,7 +744,7 @@ class ShardedEngine(RelationalMemoryEngine):
                 outs = KR.scan_shard(
                     [c.words for c in chunks], reqs,
                     revision=self.revision, block_rows=block_rows,
-                    interpret=self.interpret,
+                    interpret=self.interpret, vmem_limit=vmem,
                 )
             except Exception as err:
                 permanent = isinstance(err, faults.PermanentFault)

@@ -146,6 +146,11 @@ class EngineStats:
       shard was quarantined), and the row bytes those failover passes
       re-scanned.  All zero in a fault-free run — the ≤5% overhead gate in
       ``fig_fault_recovery`` relies on that.
+    * ``kernel_fallbacks`` — serves a Pallas revision sent to the XLA
+      fallback instead of its kernel: a failed kernel dispatch, a route the
+      circuit breaker holds open, or a join probe whose bucket arrays do
+      not fit the chip's VMEM.  Zero whenever every request of a Pallas
+      revision ran on its kernel; the ``xla`` revision never counts here.
     """
 
     hot_hits: int = 0
@@ -171,6 +176,7 @@ class EngineStats:
     bytes_saved_compression: int = 0  # plain-minus-narrow bytes codecs kept off the bus
     decodes: int = 0  # client-read decodes of encoded packed results
     decode_cache_hits: int = 0  # decode results served from the per-version cache
+    kernel_fallbacks: int = 0  # Pallas serves sent to the XLA fallback
 
     def reset(self) -> None:
         self.hot_hits = 0
@@ -196,6 +202,7 @@ class EngineStats:
         self.bytes_saved_compression = 0
         self.decodes = 0
         self.decode_cache_hits = 0
+        self.kernel_fallbacks = 0
 
 
 @dataclasses.dataclass
@@ -490,6 +497,28 @@ class DeviceRowStore:
         )
 
 
+# -------------------------------------------------- kernel-call row ranges
+def _row_widths(words: jax.Array, reqs: Sequence["KR.ScanRequest"]) -> list[int]:
+    """Word width of every row-indexed operand and output of one scan call."""
+    widths = [words.shape[1]]
+    for req in reqs:
+        if isinstance(req, (KR.ProjectRequest, KR.FilterRequest)):
+            widths.append(req.geom.out_words_per_row)
+        if isinstance(req, KR.FilterRequest):
+            widths.append(1)  # the row mask
+    return widths
+
+
+def _row_pieces(words: jax.Array, limit: int | None) -> list[jax.Array]:
+    """``words`` cut into row ranges of at most ``limit`` rows.  Rows are
+    position-local, so per-piece kernel outputs combine like per-chunk ones."""
+    n = words.shape[0]
+    if limit is None or n <= limit:
+        return [words]
+    return [jax.lax.dynamic_slice_in_dim(words, start, min(limit, n - start))
+            for start in range(0, n, limit)]
+
+
 # -------------------------------------------------- request subsumption
 def _geom_words(geom) -> tuple[int, ...]:
     """The absolute row-word indices a geometry enables, packed order."""
@@ -562,8 +591,12 @@ class RelationalMemoryEngine:
     """Host-side RME: registers ephemeral views and materializes them on access.
 
     ``revision`` selects the datapath (paper §5.2): ``"bsl"``, ``"pck"``,
-    ``"mlp"`` (Pallas kernels, validated in interpret mode on CPU), or
-    ``"xla"`` (fused gather — the path that lowers for CPU/dry-run targets).
+    ``"mlp"`` (Pallas kernels), or ``"xla"`` (fused gather).  ``interpret``
+    defaults to the backend: Mosaic-compiled kernels on a TPU, the Pallas
+    interpreter anywhere else (``engine.interpret`` records the choice).
+    ``vmem_bytes`` is the VMEM budget the fused pass and the join probe size
+    their row tiles against in interpret mode; compiled kernels size against
+    the chip's own VMEM instead (:meth:`_vmem_budget`).
     ``delta_uploads=False`` disables the whole write-path delta machinery:
     any table change re-ships the full device buffer on next access, and a
     grown table turns cached views cold instead of delta-serving them — the
@@ -575,7 +608,7 @@ class RelationalMemoryEngine:
         revision: str = "mlp",
         block_rows: int = K.DEFAULT_BLOCK_ROWS,
         cache_bytes: int = 2 << 20,
-        interpret: bool = True,
+        interpret: bool | None = None,
         vmem_bytes: int = 2 << 20,  # paper: 2 MB data SPM
         delta_uploads: bool = True,
         breaker_threshold: int = 3,
@@ -586,8 +619,9 @@ class RelationalMemoryEngine:
             raise ValueError(f"unknown revision {revision!r}; want one of {K.REVISIONS}")
         self.revision = revision
         self.block_rows = block_rows
-        self.interpret = interpret
+        self.interpret = common.resolve_interpret(interpret)
         self.vmem_bytes = vmem_bytes
+        self._chip: tuple[int, int | None] | None = None  # see _chip_limits
         self.delta = delta_uploads
         # subsumption-aware sharing: a batch member whose projection ⊆ and
         # predicate ⊇ another's is served by slicing/masking the covering
@@ -979,10 +1013,17 @@ class RelationalMemoryEngine:
             words = self.device_words(table)
             return [self._execute_solo(words, table, reqs[0])]
         chunks = self.device_chunks(table)
-        block_rows = self._fused_block_rows(reqs, table.row_words)
+        vmem = self._vmem_budget(chunks[0])
+        block_rows = self._fused_block_rows(reqs, table.row_words, vmem)
         route = (table.uid, tuple(KR._strip_dynamic(r) for r in reqs))
-        per_chunk = [self._scan_chunk(chunk, reqs, block_rows, route)
-                     for chunk in chunks]
+        # a chunk larger than one kernel call may take is scanned in row
+        # ranges, whose outputs combine like the chunks'
+        per_chunk = [
+            self._scan_chunk(piece, reqs, block_rows, vmem, route)
+            for chunk in chunks
+            for piece in _row_pieces(chunk, self._kernel_row_limit(
+                chunk, _row_widths(chunk, reqs), block_rows))
+        ]
         outs = (per_chunk[0] if len(per_chunk) == 1 else [
             KR.combine_chunk_outputs(req, [o[r] for o in per_chunk])
             for r, req in enumerate(reqs)
@@ -995,7 +1036,7 @@ class RelationalMemoryEngine:
 
     def _scan_chunk(self, chunk: jax.Array,
                     reqs: tuple["KR.ScanRequest", ...], block_rows: int,
-                    route) -> list:
+                    vmem: int, route) -> list:
         """One chunk's fused pass behind the lowering circuit breaker.
 
         A ``closed`` route attempts the Pallas pass; a failure (a real
@@ -1009,17 +1050,20 @@ class RelationalMemoryEngine:
         if self.revision == "xla":
             return KR.scan_multi_xla(chunk, tuple(reqs))
         if not self.breaker.allow(route):
+            self.stats.kernel_fallbacks += 1
             return KR.scan_multi_xla(chunk, tuple(reqs))
         try:
             faults.maybe_fault("lowering", op="scan")
             outs = KR.scan_multi(
                 chunk, reqs, revision=self.revision,
                 block_rows=block_rows, interpret=self.interpret,
+                vmem_limit=vmem,
             )
         except Exception as err:
             if isinstance(err, faults.FaultError) and err.site != "lowering":
                 raise
             self.breaker.record_failure(route)
+            self.stats.kernel_fallbacks += 1
             return KR.scan_multi_xla(chunk, tuple(reqs))
         self.breaker.record_success(route)
         return outs
@@ -1043,17 +1087,21 @@ class RelationalMemoryEngine:
             return self._solo_kernel(words, req)
         route = (table.uid, (KR._strip_dynamic(req),))
         if not self.breaker.allow(route):
+            self.stats.kernel_fallbacks += 1
             return KR.scan_multi_xla(words, (req,))[0]
+        pieces = _row_pieces(words, self._kernel_row_limit(
+            words, _row_widths(words, (req,)), self.block_rows))
         try:
             faults.maybe_fault("lowering", op="scan")
-            out = self._solo_kernel(words, req)
+            outs = [self._solo_kernel(piece, req) for piece in pieces]
         except Exception as err:
             if isinstance(err, faults.FaultError) and err.site != "lowering":
                 raise
             self.breaker.record_failure(route)
+            self.stats.kernel_fallbacks += 1
             return KR.scan_multi_xla(words, (req,))[0]
         self.breaker.record_success(route)
-        return out
+        return outs[0] if len(outs) == 1 else KR.combine_chunk_outputs(req, outs)
 
     def _solo_kernel(self, words: jax.Array, req: "KR.ScanRequest"):
         """Single-op kernel dispatch (bsl/pck revisions stay exercised)."""
@@ -1168,43 +1216,98 @@ class RelationalMemoryEngine:
                     route=None):
         """One probe pass with the per-query lowering-failure fallback: the
         Pallas grid pass when the revision supports it, else — or on any
-        lowering error — the fused-gather XLA probe (same results).  The
-        probe honors the same SPM budget as the fused scan: the row tile is
-        halved until the modeled working set (row tile + resident bucket
-        arrays) fits ``vmem_bytes``.  ``route`` threads the caller's
-        circuit-breaker key so repeated lowering failures flip the route
-        ``open`` and skip the doomed attempt during the cooldown."""
+        lowering error — the fused-gather XLA probe (same results).  Before
+        dispatch the row tile is halved until the modeled working set (row
+        tile + resident bucket arrays) fits :meth:`_vmem_budget`; on a chip a
+        build side too large for any tile takes the XLA probe, counted in
+        ``kernel_fallbacks`` like every other fallback serve.  ``route``
+        threads the caller's circuit-breaker key so repeated lowering
+        failures flip the route ``open`` and skip the doomed attempt during
+        the cooldown."""
+        def xla_probe():
+            return K.hash_join_xla(words, partitions, key_word, val_word,
+                                   ts_word=ts_word, ts=ts, build_ts=build_ts)
+
         if self.revision == "xla":
-            return K.hash_join_xla(words, partitions, key_word, val_word,
-                                   ts_word=ts_word, ts=ts, build_ts=build_ts)
-        block_rows = self.block_rows
-        while (block_rows // 2 >= MIN_FUSED_BLOCK_ROWS
-               and K.probe_vmem_footprint_bytes(
-                   partitions, words.shape[1], block_rows) > self.vmem_bytes):
-            block_rows //= 2
+            return xla_probe()
+        vmem = self._vmem_budget(words)
+        block_rows = self._probe_block_rows(partitions, words.shape[1], vmem)
+        if block_rows is None and self.interpret:
+            # the interpreter has no VMEM to exhaust: the floor tile serves
+            block_rows = MIN_FUSED_BLOCK_ROWS
+        if block_rows is None or (route is not None
+                                  and not self.breaker.allow(route)):
+            self.stats.kernel_fallbacks += 1
+            return xla_probe()
         self.stats.last_block_rows = block_rows
-        if route is not None and not self.breaker.allow(route):
-            return K.hash_join_xla(words, partitions, key_word, val_word,
-                                   ts_word=ts_word, ts=ts, build_ts=build_ts)
+        pieces = _row_pieces(words, self._kernel_row_limit(
+            words, (words.shape[1], 1, 1, 1), block_rows))
         try:
             faults.maybe_fault("lowering", op="join")
-            out = K.hash_join(words, partitions, key_word, val_word,
-                              ts_word=ts_word, ts=ts, build_ts=build_ts,
-                              revision=self.revision,
-                              block_rows=block_rows,
-                              interpret=self.interpret)
+            outs = [K.hash_join(piece, partitions, key_word, val_word,
+                                ts_word=ts_word, ts=ts, build_ts=build_ts,
+                                revision=self.revision,
+                                block_rows=block_rows,
+                                interpret=self.interpret, vmem_limit=vmem)
+                    for piece in pieces]
         except Exception as err:
             if isinstance(err, faults.FaultError) and err.site != "lowering":
                 raise
-            # mirror the PR 3 hardening: one query's lowering failure falls
-            # back to the XLA probe instead of poisoning the batch
+            # one query's lowering failure falls back to the XLA probe
+            # instead of poisoning the batch
             if route is not None:
                 self.breaker.record_failure(route)
-            return K.hash_join_xla(words, partitions, key_word, val_word,
-                                   ts_word=ts_word, ts=ts, build_ts=build_ts)
+            self.stats.kernel_fallbacks += 1
+            return xla_probe()
         if route is not None:
             self.breaker.record_success(route)
-        return out
+        if len(outs) == 1:
+            return outs[0]
+        return tuple(jnp.concatenate(col) for col in zip(*outs))
+
+    def _chip_limits(self, words: jax.Array) -> tuple[int, int | None]:
+        """``(scoped VMEM a compiled kernel may take, HBM bytes)`` of the chip
+        that holds ``words``, asked of the device once per engine."""
+        if self._chip is None:
+            device = next(iter(words.devices()))
+            hbm = (device.memory_stats() or {}).get("bytes_limit")
+            self._chip = (common.vmem_limit_bytes(device.device_kind), hbm)
+        return self._chip
+
+    def _vmem_budget(self, words: jax.Array) -> int:
+        """The one VMEM budget both row-tile guards (fused pass, join probe)
+        size against, and the scoped-VMEM limit the kernels compile with:
+        the paper's SPM, ``vmem_bytes``, in interpret mode; compiled, the
+        share of VMEM a kernel may take on the chip that holds ``words``."""
+        if self.interpret:
+            return self.vmem_bytes
+        return self._chip_limits(words)[0]
+
+    def _kernel_row_limit(self, words: jax.Array, widths,
+                          block_rows: int) -> int | None:
+        """Most rows one compiled kernel call over ``words`` takes
+        (:func:`repro.kernels.common.kernel_row_limit` of the HBM its chip
+        reports); ``None`` in interpret mode, where nothing is relaid out,
+        or where the device reports no memory limit."""
+        if self.interpret:
+            return None
+        hbm = self._chip_limits(words)[1]
+        if hbm is None:
+            return None
+        return common.kernel_row_limit(hbm, widths, block_rows)
+
+    def _probe_block_rows(self, partitions, row_words: int,
+                          vmem: int) -> int | None:
+        """The largest row tile (halving from ``block_rows``, never below
+        ``MIN_FUSED_BLOCK_ROWS``) whose modeled probe working set fits
+        ``vmem``; ``None`` when even the smallest tile does not."""
+        block_rows = self.block_rows
+        while (K.probe_vmem_footprint_bytes(partitions, row_words, block_rows)
+               > vmem):
+            if block_rows // 2 < MIN_FUSED_BLOCK_ROWS:
+                return None
+            block_rows //= 2
+        return block_rows
 
     def _join_direct(self, op: JoinOp) -> JoinResult:
         """Solo join: stream the probe kernel over the device row-store
@@ -1341,13 +1444,14 @@ class RelationalMemoryEngine:
         return out
 
     def _fused_block_rows(self, reqs: Sequence["KR.ScanRequest"],
-                          row_words: int) -> int:
+                          row_words: int, vmem: int) -> int:
         """SPM budget guard: halve the row tile until the fused pass's modeled
-        VMEM working set fits ``vmem_bytes`` (never below the floor)."""
+        VMEM working set fits ``vmem`` (:meth:`_vmem_budget`; never below
+        the floor)."""
         block_rows = self.block_rows
         while (block_rows // 2 >= MIN_FUSED_BLOCK_ROWS
                and K.scan_vmem_footprint_bytes(reqs, row_words, block_rows)
-               > self.vmem_bytes):
+               > vmem):
             block_rows //= 2
         self.stats.last_block_rows = block_rows
         return block_rows

@@ -19,7 +19,8 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from repro.compat import pcast, shard_map
+from jax import shard_map
+from jax.lax import pcast
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 

@@ -43,6 +43,60 @@ from repro.core.schema import TableGeometry
 
 DEFAULT_BLOCK_ROWS = 256
 
+# VMEM of one TensorCore per TPU ``device_kind``, as the TPU compiler
+# reports it for a v5e (128 MiB).  A kind missing here is an error, never a
+# default: kernels that size their working set against VMEM must not guess.
+VMEM_BYTES_BY_KIND = {
+    "TPU v5 lite": 128 << 20,
+}
+
+# scoped-VMEM limit the kernels that opt in (the join probe) compile under:
+# Mosaic's default is 16 MiB, too small for the resident bucket arrays, so
+# the probe asks for this share of the core's VMEM explicitly
+VMEM_LIMIT_FRACTION = 0.75
+
+
+def resolve_interpret(interpret: bool | None) -> bool:
+    """The one place ``interpret`` is decided: ``None`` means "from the
+    backend" — Mosaic-compiled kernels on a TPU, the Pallas interpreter
+    anywhere else."""
+    if interpret is None:
+        return jax.default_backend() != "tpu"
+    return interpret
+
+
+def vmem_limit_bytes(device_kind: str) -> int:
+    """Scoped-VMEM budget of one kernel on a TPU of ``device_kind``.  Raises
+    for a kind not in :data:`VMEM_BYTES_BY_KIND`."""
+    if device_kind not in VMEM_BYTES_BY_KIND:
+        raise ValueError(
+            f"unknown TPU device kind {device_kind!r}: add its VMEM size to "
+            "repro.kernels.common.VMEM_BYTES_BY_KIND"
+        )
+    return int(VMEM_BYTES_BY_KIND[device_kind] * VMEM_LIMIT_FRACTION)
+
+
+# share of the device's HBM the relayout copies of one kernel call may take
+KERNEL_HBM_FRACTION = 0.25
+
+
+def kernel_row_limit(hbm_bytes: int, widths, block_rows: int) -> int:
+    """Most rows one compiled Pallas call may take on a device with
+    ``hbm_bytes`` of HBM: a power of two, never below ``block_rows``.
+
+    A TPU keeps a narrow ``(rows, w)`` int32 array compact in HBM (rows on
+    the lanes), but a Pallas call gets each row-indexed operand and output
+    as a row-major copy padded to whole 128-lane tiles: ``rows * 512 B`` for
+    every ``w <= 128``.  ``widths`` lists the word width of each of them;
+    their copies together must fit :data:`KERNEL_HBM_FRACTION` of the HBM.
+    """
+    row_bytes = sum(-(-w // 128) for w in widths) * 128 * 4
+    fit = int(hbm_bytes * KERNEL_HBM_FRACTION) // row_bytes
+    limit = block_rows
+    while limit * 2 <= fit:
+        limit *= 2
+    return limit
+
 
 def decode(x: jax.Array, dtype: str) -> jax.Array:
     """Reinterpret raw int32 storage words as the column's 4-byte dtype."""
@@ -62,6 +116,18 @@ def pred_mask(vals: jax.Array, op: str, k: jax.Array) -> jax.Array:
     if op == "none":
         return jnp.ones(vals.shape, dtype=bool)
     raise ValueError(op)
+
+
+def tile_row_ids(i, block_rows: int) -> jax.Array:
+    """Global row index of each row in grid step ``i``'s row tile, ``(B,)``.
+
+    Taken from a ``(B, 1)`` iota, so it has the layout Mosaic gives a row-tile
+    column ``x_ref[:, w]``: a mask built from it alone (the ``none``
+    predicate) can still be widened to ``(B, 1)``, which a 1-D iota's mask
+    cannot ("unsupported shape cast").
+    """
+    return i * block_rows + jax.lax.broadcasted_iota(
+        jnp.int32, (block_rows, 1), 0)[:, 0]
 
 
 def group_ids(raw: jax.Array, num_groups: int) -> jax.Array:

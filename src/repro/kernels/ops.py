@@ -54,7 +54,7 @@ def project_any(
     geom: TableGeometry,
     revision: str = "mlp",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Dispatch projection across revisions, including the XLA path."""
     if revision == "xla":

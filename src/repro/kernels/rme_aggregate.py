@@ -22,7 +22,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .common import DEFAULT_BLOCK_ROWS, group_ids
+from .common import DEFAULT_BLOCK_ROWS, group_ids, resolve_interpret, tile_row_ids
 from .common import decode as _decode
 from .common import pred_mask as _pred
 
@@ -46,7 +46,7 @@ def _agg_kernel(
     k = _decode(k_ref[0, 0], pred_dtype)
     mask = _pred(_decode(x_ref[:, pred_word], pred_dtype), pred_op, k)
     # padded tail rows (beyond the true row count) never contribute
-    ridx = i * block_rows + jax.lax.iota(jnp.int32, block_rows)
+    ridx = tile_row_ids(i, block_rows)
     mask = mask & (ridx < n_rows)
     if ts_word >= 0:
         ts = ts_ref[0, 0]
@@ -54,8 +54,9 @@ def _agg_kernel(
         end = x_ref[:, ts_word + 1]
         mask = mask & (begin <= ts) & (ts < end)
     fm = mask.astype(jnp.float32)
-    o_ref[0, 0] += jnp.sum(vals * fm)
-    o_ref[0, 1] += jnp.sum(fm)
+    # a (1, 2) vector store: Mosaic cannot store scalars to VMEM
+    contrib = jnp.stack([vals * fm, fm], axis=1)  # (B, 2)
+    o_ref[...] += jnp.sum(contrib, axis=0, keepdims=True)
 
 
 @functools.partial(
@@ -82,7 +83,7 @@ def aggregate(
     ts: int = 0,
     ts_word: int = -1,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """``SELECT SUM(a), COUNT(*) FROM t WHERE pred(b)`` fused in the engine.
 
@@ -112,7 +113,7 @@ def aggregate(
         ],
         out_specs=pl.BlockSpec((1, 2), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((1, 2), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words, k_bits, ts_arr)
     return out[0]
 
@@ -137,7 +138,7 @@ def _groupby_kernel(
     vals = _decode(x_ref[:, agg_word], agg_dtype).astype(jnp.float32)
     k = _decode(k_ref[0, 0], pred_dtype)
     mask = _pred(_decode(x_ref[:, pred_word], pred_dtype), pred_op, k)
-    ridx = i * block_rows + jax.lax.iota(jnp.int32, block_rows)
+    ridx = tile_row_ids(i, block_rows)
     mask = mask & (ridx < n_rows)
     if ts_word >= 0:
         ts = ts_ref[0, 0]
@@ -149,7 +150,8 @@ def _groupby_kernel(
     )  # (B, G)
     contrib = jnp.stack([vals * fm, fm], axis=1)  # (B, 2)
     o_ref[...] += jax.lax.dot_general(
-        onehot, contrib, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, contrib, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST, preferred_element_type=jnp.float32,
     )  # (G, 2)
 
 
@@ -181,7 +183,7 @@ def groupby_sum(
     ts: int = 0,
     ts_word: int = -1,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """``SELECT SUM(a), COUNT(*) ... GROUP BY g`` via one-hot MXU contraction.
 
@@ -213,6 +215,6 @@ def groupby_sum(
         ],
         out_specs=pl.BlockSpec((num_groups, 2), lambda i: (0, 0)),
         out_shape=jax.ShapeDtypeStruct((num_groups, 2), jnp.float32),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words, k_bits, ts_arr)
     return out[:, 0], out[:, 1]

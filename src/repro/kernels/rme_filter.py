@@ -18,7 +18,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.schema import TableGeometry
 
-from .common import DEFAULT_BLOCK_ROWS
+from .common import DEFAULT_BLOCK_ROWS, resolve_interpret, tile_row_ids
 from .common import decode as _decode
 from .common import pred_mask as _pred
 
@@ -30,7 +30,7 @@ def _filter_kernel(spec, x_ref, k_ref, ts_ref, o_ref, m_ref):
 
     k = _decode(k_ref[0, 0], pred_dtype)
     mask = _pred(_decode(x_ref[:, pred_word], pred_dtype), pred_op, k)
-    ridx = i * block_rows + jax.lax.iota(jnp.int32, block_rows)
+    ridx = tile_row_ids(i, block_rows)
     mask = mask & (ridx < n_rows)
     if ts_word >= 0:
         ts = ts_ref[0, 0]
@@ -59,7 +59,7 @@ def filter_project(
     ts: int = 0,
     ts_word: int = -1,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns ``(packed (N, out_words) int32, mask (N,) bool)``."""
     n, row_words = words.shape
@@ -96,6 +96,6 @@ def filter_project(
             jax.ShapeDtypeStruct((n_pad, out_w), jnp.int32),
             jax.ShapeDtypeStruct((n_pad, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words, k_bits, ts_arr)
     return packed[:n], mask[:n, 0].astype(bool)

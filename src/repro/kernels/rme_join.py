@@ -23,7 +23,9 @@ TPU adaptation: buckets are selected with a one-hot MXU contraction (the
 ``groupby_sum`` idiom), not a gather.  Because float32 matmuls are only exact
 to 2^24, every int32 bucket column travels as two exact 16-bit halves through
 the contraction and is recombined bitwise afterwards — bit-exact selection on
-the MXU, no dynamic indexing in the kernel.
+the MXU, no dynamic indexing in the kernel.  The contraction asks for
+``Precision.HIGHEST``: at the TPU's default precision the halves would pass
+through bfloat16 (8 significant bits) and lose their low bits.
 
 The bucket hash is **Fibonacci multiplicative hashing**: ``bucket = (key *
 2654435761) >>> (32 - log2 P)`` (the top bits of the wrapped product, same
@@ -44,9 +46,10 @@ columns let the same snapshot test run against the *build* rows — one cached
 partition set serves any snapshot time, because ``ts`` is a traced operand.
 
 ``hash_join_xla`` is the fused-gather fallback (plain ``jnp.take`` bucket
-lookup) used for the ``xla`` revision and as the per-query escape when the
-Pallas probe fails to lower — non-TPU targets keep working, mirroring
-``scan_multi_xla``.
+lookup) used for the ``xla`` revision, for a build side whose bucket arrays
+do not fit the chip's VMEM (decided before dispatch from
+:func:`probe_vmem_footprint_bytes`), and as the per-query escape when the
+Pallas probe fails to lower, mirroring ``scan_multi_xla``.
 """
 
 from __future__ import annotations
@@ -58,8 +61,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from .common import DEFAULT_BLOCK_ROWS, pad_rows
+from .common import DEFAULT_BLOCK_ROWS, pad_rows, resolve_interpret, tile_row_ids
 
 # target average bucket occupancy: P is the smallest power of two with
 # n_rows / P <= TARGET_BUCKET_LOAD (capacity C is then the observed maximum)
@@ -210,8 +214,10 @@ def _onehot_select(onehot: jax.Array, bucket_words: jax.Array) -> jax.Array:
     hi, lo = _split16(bucket_words)
     dims = (((1,), (0,)), ((), ()))
     sel_hi = jax.lax.dot_general(onehot, hi, dims,
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32)
     sel_lo = jax.lax.dot_general(onehot, lo, dims,
+                                 precision=jax.lax.Precision.HIGHEST,
                                  preferred_element_type=jnp.float32)
     return _merge16(sel_hi, sel_lo)
 
@@ -232,7 +238,7 @@ def _probe_kernel(key_word, val_word, ts_word, build_ts, n_rows,
     if build_ts:
         match = match & (_onehot_select(onehot, bb_ref[...]) <= ts)
         match = match & (ts < _onehot_select(onehot, be_ref[...]))
-    ridx = i * block_rows + jax.lax.iota(jnp.int32, block_rows)
+    ridx = tile_row_ids(i, block_rows)
     valid = ridx < n_rows
     if ts_word >= 0:
         valid = valid & (x_ref[:, ts_word] <= ts) & (ts < x_ref[:, ts_word + 1])
@@ -248,7 +254,7 @@ def _probe_kernel(key_word, val_word, ts_word, build_ts, n_rows,
 @functools.partial(
     jax.jit,
     static_argnames=("key_word", "val_word", "ts_word", "build_ts",
-                     "block_rows", "interpret"),
+                     "block_rows", "interpret", "vmem_limit"),
 )
 def _hash_join(
     words: jax.Array,
@@ -262,7 +268,8 @@ def _hash_join(
     ts_word: int,
     build_ts: bool,
     block_rows: int,
-    interpret: bool,
+    interpret: bool | None,
+    vmem_limit: int | None,
 ):
     n, row_words = words.shape
     x = pad_rows(words, block_rows)
@@ -282,7 +289,9 @@ def _hash_join(
         ],
         out_specs=[col, col, col],
         out_shape=[out_shape, out_shape, out_shape],
-        interpret=interpret,
+        compiler_params=(None if vmem_limit is None else
+                         pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)),
+        interpret=resolve_interpret(interpret),
     )(x, bk, bv, bb, be, ts_arr)
 
 
@@ -296,7 +305,8 @@ def hash_join(
     build_ts: bool = False,
     revision: str = "mlp",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
+    vmem_limit: int | None = None,
 ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Probe ``words`` (a row-store chunk or a packed block) against cached
     build partitions; returns ``(s_proj, r_proj, matched)`` with one slot per
@@ -309,7 +319,9 @@ def hash_join(
     the same test against the build rows' bucketed timestamps.  ``ts`` is a
     traced operand: distinct snapshot times never retrace.  Rows are
     position-local, so per-chunk outputs concatenate (the
-    ``scan_multi_chunked`` contract).
+    ``scan_multi_chunked`` contract).  ``vmem_limit`` raises the compiled
+    kernel's scoped-VMEM limit (the bucket arrays stay resident); size it
+    with :func:`probe_vmem_footprint_bytes` before dispatch.
     """
     if revision == "xla":
         return hash_join_xla(words, partitions, key_word, val_word,
@@ -319,7 +331,7 @@ def hash_join(
     s, r, m = _hash_join(
         words, *partitions, ts_arr, key_word=key_word, val_word=val_word,
         ts_word=ts_word, build_ts=build_ts, block_rows=block_rows,
-        interpret=interpret,
+        interpret=interpret, vmem_limit=vmem_limit,
     )
     return s[:n, 0], r[:n, 0], m[:n, 0].astype(bool)
 
@@ -369,14 +381,33 @@ def hash_join_xla(
                           build_ts=build_ts)
 
 
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
 def probe_vmem_footprint_bytes(
     partitions: JoinPartitions, row_words: int,
     block_rows: int = DEFAULT_BLOCK_ROWS,
 ) -> int:
-    """Modeled VMEM working set of one probe grid step: the double-buffered
-    row tile and output columns, plus the bucket arrays resident for the
-    whole pass."""
-    return (2 * block_rows * (row_words + 3) * 4) + partitions.nbytes
+    """Modeled VMEM working set of one compiled probe grid step, in bytes.
+
+    Every VMEM array is tiled ``(8, 128)``, so narrow minor dimensions are
+    charged at 128 lanes: the double-buffered row tile and three ``(B, 1)``
+    output columns, the four double-buffered ``(P, C)`` bucket arrays, the
+    ``(B, P)`` float32 one-hot plus its three bfloat16 pieces (what a
+    ``Precision.HIGHEST`` contraction splits it into), and one bucket
+    array's 16-bit halves with their pieces.  An upper bound of what Mosaic
+    allocates, checked against compiles for a v5e in
+    ``tests/test_tpu_compile.py``.
+    """
+    b = _pad(block_rows, 8)
+    p = _pad(partitions.num_buckets, 8)
+    c = _pad(partitions.capacity, 128)
+    tiles = 2 * b * (_pad(row_words, 128) + 3 * 128) * 4
+    buckets = 4 * 2 * p * c * 4
+    onehot = b * p * (4 + 3 * 2)
+    halves = 2 * p * c * (4 + 3 * 2) + 2 * b * c * 4
+    return tiles + buckets + onehot + halves
 
 
 def broadcast_partitions(

@@ -37,6 +37,7 @@ from repro.core.schema import TableGeometry
 
 from .common import DEFAULT_BLOCK_ROWS, column_slices as _column_slices
 from .common import pad_rows as _pad_rows
+from .common import resolve_interpret
 
 __all__ = [
     "DEFAULT_BLOCK_ROWS", "project", "project_xla", "vmem_footprint_bytes",
@@ -82,13 +83,13 @@ def project(
     geom: TableGeometry,
     revision: str = "mlp",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Packed projection ``(N, row_words) -> (N, out_words)`` via the RME.
 
-    ``interpret=True`` executes the kernel body on CPU (validation); on a real
-    TPU deployment this flag is dropped and the same BlockSpecs drive HBM→VMEM
-    DMA.  ``words.shape[1]`` may exceed ``geom.row_words`` (hidden MVCC words
+    ``interpret=None`` picks from the backend: the Pallas interpreter off
+    the TPU (validation), Mosaic-compiled BlockSpecs driving HBM→VMEM DMA on
+    it.  ``words.shape[1]`` may exceed ``geom.row_words`` (hidden MVCC words
     ride along in storage but are never shipped unless enabled).
     """
     n, row_words = words.shape
@@ -110,7 +111,7 @@ def project(
             in_specs=[pl.BlockSpec((block_rows, row_words), lambda i: (i, 0))],
             out_specs=pl.BlockSpec((block_rows, out_w), lambda i: (i, 0)),
             out_shape=out_shape,
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(x)
     elif revision == "pck":
         out = pl.pallas_call(
@@ -120,7 +121,7 @@ def project(
             out_specs=pl.BlockSpec((block_rows, out_w), lambda i, j: (i, 0)),
             out_shape=out_shape,
             scratch_shapes=[pltpu.VMEM((block_rows, out_w), jnp.int32)],
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(x)
     elif revision == "bsl":
         out = pl.pallas_call(
@@ -129,7 +130,7 @@ def project(
             in_specs=[in_spec_row],
             out_specs=pl.BlockSpec((block_rows, out_w), lambda i, j: (i, 0)),
             out_shape=out_shape,
-            interpret=interpret,
+            interpret=resolve_interpret(interpret),
         )(x)
     else:
         raise ValueError(f"unknown RME revision {revision!r}")

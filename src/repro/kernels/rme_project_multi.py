@@ -31,6 +31,7 @@ from repro.core.schema import TableGeometry
 from .common import DEFAULT_BLOCK_ROWS
 from .common import column_slices as _column_slices
 from .common import pad_rows as _pad_rows
+from .common import resolve_interpret
 
 
 def _mlp_multi_kernel(view_slices, x_ref, *o_refs):
@@ -58,7 +59,7 @@ def project_multi(
     geoms: tuple[TableGeometry, ...],
     revision: str = "mlp",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, ...]:
     """Shared-scan projection ``(N, row_words) -> [(N, out_words_v), ...]``.
 
@@ -89,7 +90,7 @@ def project_multi(
             jax.ShapeDtypeStruct((n_pad, g.out_words_per_row), jnp.int32)
             for g in geoms
         ),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x)
     return tuple(o[:n] for o in outs)
 

@@ -51,6 +51,7 @@ from typing import Sequence
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.schema import WORD, TableGeometry, geometry_from_intervals
 
@@ -62,6 +63,8 @@ from .common import (
     pad_rows,
     pred_k_bits,
     pred_mask,
+    resolve_interpret,
+    tile_row_ids,
 )
 
 
@@ -206,7 +209,7 @@ def _fused_mask(req, i, block_rows, n_rows, x_ref, k_ref, ts_ref, r):
     k = decode(k_ref[r, 0], req.pred_dtype)
     mask = pred_mask(decode(x_ref[:, req.pred_word], req.pred_dtype),
                      req.pred_op, k)
-    ridx = i * block_rows + jax.lax.iota(jnp.int32, block_rows)
+    ridx = tile_row_ids(i, block_rows)
     mask = mask & (ridx < n_rows)
     if req.ts_word >= 0:
         ts = ts_ref[r, 0]
@@ -241,17 +244,18 @@ def _scan_multi_kernel(requests, n_rows, x_ref, k_ref, ts_ref, *o_refs):
 
         vals = decode(x_ref[:, req.agg_word], req.agg_dtype).astype(jnp.float32)
         fm = mask.astype(jnp.float32)
+        contrib = jnp.stack([vals * fm, fm], axis=1)  # (B, 2)
         if isinstance(req, AggregateRequest):
-            o_ref[0, 0] += jnp.sum(vals * fm)
-            o_ref[0, 1] += jnp.sum(fm)
+            # a (1, 2) vector store: Mosaic cannot store scalars to VMEM
+            o_ref[...] += jnp.sum(contrib, axis=0, keepdims=True)
         else:  # GroupByRequest: one-hot × matmul MXU contraction
             g = group_ids(x_ref[:, req.group_word], req.num_groups)
             onehot = (
                 g[:, None] == jax.lax.iota(jnp.int32, req.num_groups)[None, :]
             ).astype(jnp.float32)  # (B, G)
-            contrib = jnp.stack([vals * fm, fm], axis=1)  # (B, 2)
             o_ref[...] += jax.lax.dot_general(
                 onehot, contrib, (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
                 preferred_element_type=jnp.float32,
             )  # (G, 2)
 
@@ -268,7 +272,8 @@ def _check_requests(row_words: int, requests: Sequence[ScanRequest]) -> None:
 
 
 @functools.partial(
-    jax.jit, static_argnames=("requests", "block_rows", "interpret")
+    jax.jit,
+    static_argnames=("requests", "block_rows", "interpret", "vmem_limit"),
 )
 def _scan_multi(
     words: jax.Array,
@@ -277,6 +282,7 @@ def _scan_multi(
     requests: tuple[ScanRequest, ...],
     block_rows: int,
     interpret: bool,
+    vmem_limit: int | None = None,
 ):
     n, row_words = words.shape
     x = pad_rows(words, block_rows)
@@ -314,7 +320,9 @@ def _scan_multi(
         ],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        compiler_params=(None if vmem_limit is None else
+                         pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)),
+        interpret=resolve_interpret(interpret),
     )(x, k_bits, ts_arr)
 
 
@@ -342,7 +350,8 @@ def scan_multi(
     requests: Sequence[ScanRequest],
     revision: str = "mlp",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
+    vmem_limit: int | None = None,
 ) -> list:
     """One row-store pass serving a heterogeneous request batch.
 
@@ -351,7 +360,8 @@ def scan_multi(
     ``(packed, bool mask)`` pairs for filters, float32 ``[sum, count]`` for
     aggregates, and ``(sums[G], counts[G])`` for group-bys.  The predicate
     constants and snapshot times are traced operands — distinct values do not
-    retrace the kernel.
+    retrace the kernel.  ``vmem_limit`` sets the compiled kernel's
+    scoped-VMEM limit (the budget its row tile was sized against).
     """
     if revision == "xla":
         return scan_multi_xla(words, tuple(requests))
@@ -360,7 +370,7 @@ def scan_multi(
     k_bits, ts_arr = _dynamic_operands(requests)
     flat = _scan_multi(
         words, k_bits, ts_arr, tuple(_strip_dynamic(r) for r in requests),
-        block_rows, interpret,
+        block_rows, interpret, vmem_limit,
     )
     return _unflatten(requests, flat, n)
 
@@ -399,7 +409,7 @@ def scan_multi_chunked(
     requests: Sequence[ScanRequest],
     revision: str = "mlp",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> list:
     """One fused pass per resident chunk, combined into per-request results.
 
@@ -414,7 +424,8 @@ def scan_multi_chunked(
                           block_rows=block_rows, interpret=interpret)
     per_chunk = [
         scan_multi(chunk, requests, revision=revision,
-                   block_rows=block_rows, interpret=interpret)
+                   block_rows=block_rows, interpret=interpret,
+                   vmem_limit=vmem_limit)
         for chunk in chunks
     ]
     return [
@@ -446,7 +457,8 @@ def scan_shard(
     requests: Sequence[ScanRequest],
     revision: str = "mlp",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
+    vmem_limit: int | None = None,
 ) -> list[list]:
     """Shard-local entry point: one fused pass over each resident chunk of
     one shard (bank), per-chunk outputs left **uncombined**.
@@ -462,7 +474,8 @@ def scan_shard(
     """
     return [
         scan_multi(chunk, requests, revision=revision,
-                   block_rows=block_rows, interpret=interpret)
+                   block_rows=block_rows, interpret=interpret,
+                   vmem_limit=vmem_limit)
         for chunk in chunks
     ]
 

@@ -27,7 +27,7 @@ from jax.experimental import pallas as pl
 
 from repro.core.schema import TableGeometry
 
-from .common import DEFAULT_BLOCK_ROWS
+from .common import DEFAULT_BLOCK_ROWS, resolve_interpret, tile_row_ids
 from .common import decode as _decode
 from .common import pred_mask as _pred
 
@@ -39,7 +39,7 @@ def _select_kernel(spec, x_ref, k_ref, ts_ref, o_ref, c_ref):
 
     k = _decode(k_ref[0, 0], pred_dtype)
     keep = _pred(_decode(x_ref[:, pred_word], pred_dtype), pred_op, k)
-    ridx = i * block_rows + jax.lax.iota(jnp.int32, block_rows)
+    ridx = tile_row_ids(i, block_rows)
     keep = keep & (ridx < n_rows)
     if ts_word >= 0:
         ts = ts_ref[0, 0]
@@ -73,7 +73,7 @@ def select_compact(
     ts: int = 0,
     ts_word: int = -1,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> tuple[jax.Array, jax.Array]:
     """Returns ``(blocks (n_blocks, block_rows, out_w), counts (n_blocks,))``.
 
@@ -115,7 +115,7 @@ def select_compact(
             jax.ShapeDtypeStruct((n_pad, out_w), jnp.int32),
             jax.ShapeDtypeStruct((grid, 1), jnp.int32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(words, k_bits, ts_arr)
     return blocks.reshape(grid, block_rows, out_w), counts[:, 0]
 

@@ -28,7 +28,7 @@ import traceback
 
 import jax
 import jax.numpy as jnp
-from repro.compat import set_mesh
+from jax.sharding import set_mesh
 
 from repro.configs import SHAPES, ARCH_NAMES, cell_status, get_config
 from repro.distributed.partitioning import axis_rules, rules_for_mesh

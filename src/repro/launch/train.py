@@ -16,7 +16,7 @@ import argparse
 
 import jax
 import jax.numpy as jnp
-from repro.compat import set_mesh
+from jax.sharding import set_mesh
 
 from repro.configs import get_config, get_smoke_config
 from repro.data import RecordStore, TrainPipeline, synthetic_corpus

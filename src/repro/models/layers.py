@@ -20,7 +20,7 @@ import math
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax import lax
 
 from repro.distributed.partitioning import (

@@ -385,6 +385,7 @@ class ServerStats:
     # fault-tolerance counters (docs/reliability.md)
     retries: int = 0  # per-ticket transient-fault retry attempts
     poisoned: int = 0  # tickets that exhausted retries -> quarantined plans
+    shared_pass_fallbacks: int = 0  # failed shared passes re-run query by query
     streams: int = 0  # streaming tickets served
     stream_chunks: int = 0  # chunks pushed across all streams
     # write-path counters
@@ -1010,6 +1011,7 @@ class QueryServer:
             # healthy ticket still resolves with its result and only the
             # offender carries the error.  (PMU counters may over-charge the
             # aborted shared attempt — accounting noise, not a result bug.)
+            self.stats.shared_pass_fallbacks += 1
             for req, pq in zip(reads, compiled):
                 if pq is None:
                     continue
@@ -1314,6 +1316,7 @@ class QueryServer:
             "degraded": self.stats.degraded,
             "retries": self.stats.retries,
             "poisoned": self.stats.poisoned,
+            "shared_pass_fallbacks": self.stats.shared_pass_fallbacks,
             "poison_quarantined": len(self._poisoned),
             "streams": self.stats.streams,
             "stream_chunks": self.stats.stream_chunks,
@@ -1349,6 +1352,7 @@ class QueryServer:
             "engine_bytes_saved_compression": e.bytes_saved_compression,
             "engine_decodes": e.decodes,
             "engine_decode_cache_hits": e.decode_cache_hits,
+            "engine_kernel_fallbacks": e.kernel_fallbacks,
         })
         out.update(self.engine.breaker.snapshot())
         if hasattr(self.engine, "shard_health"):

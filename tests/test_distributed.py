@@ -132,7 +132,6 @@ def test_gpipe_pipeline_matches_sequential():
 def test_compressed_collectives():
     run_child("""
         import numpy as np, jax, jax.numpy as jnp
-        from repro import compat
         from jax.sharding import PartitionSpec as P
         from repro.distributed.collectives import tree_psum_compressed
         from repro.launch.mesh import make_mesh
@@ -143,7 +142,7 @@ def test_compressed_collectives():
         res = jax.tree.map(jnp.zeros_like, g)
         def red(mode):
             f = lambda gl, rl: tree_psum_compressed(gl, rl, "data", mode=mode)
-            return compat.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
+            return jax.shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
                                  out_specs=(P("data"), P("data")))
         exact, _ = red("none")(g, res)
         bf, _ = red("bf16")(g, res)
@@ -159,7 +158,6 @@ def test_sharded_train_step_runs_and_matches_single_device():
     """Real (not dry) sharded train step on 8 devices == 1-device result."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp, dataclasses
-        from repro import compat
         from repro.configs import get_smoke_config
         from repro.distributed.partitioning import axis_rules, rules_for_mesh
         from repro.launch import specs as S
@@ -187,7 +185,7 @@ def test_sharded_train_step_runs_and_matches_single_device():
         mesh = make_mesh((4, 2), ("data", "model"))
         rules = rules_for_mesh(mesh)
         with axis_rules(rules, dict(zip(mesh.axis_names, mesh.devices.shape))), \\
-             compat.set_mesh(mesh):
+             jax.sharding.set_mesh(mesh):
             state_sh = S.train_state_shardings(
                 mesh, jax.eval_shape(lambda: state))
             batch_sh = S.batch_shardings(mesh, batch)
@@ -211,7 +209,6 @@ def test_sp_decode_matches_single_device():
     """Sequence-parallel decode (shard_map path) == unsharded decode."""
     run_child("""
         import numpy as np, jax, jax.numpy as jnp, dataclasses
-        from repro import compat
         from repro.configs import get_smoke_config
         from repro.distributed.partitioning import axis_rules, rules_for_mesh
         from repro.launch import specs as S
@@ -236,7 +233,7 @@ def test_sp_decode_matches_single_device():
         mesh = make_mesh((2, 4), ("data", "model"))
         rules = rules_for_mesh(mesh)
         with axis_rules(rules, dict(zip(mesh.axis_names, mesh.devices.shape))), \\
-             compat.set_mesh(mesh):
+             jax.sharding.set_mesh(mesh):
             logits1, cache1 = jax.jit(lambda p, b: model.prefill(p, b, max_len))(
                 params, {"tokens": toks})
             l_sp, _ = jax.jit(model.decode_step)(
@@ -253,7 +250,6 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
     ckpt = str(tmp_path / "elastic")
     save_code = f"""
         import numpy as np, jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_smoke_config
         from repro.distributed.partitioning import axis_rules, rules_for_mesh
         from repro.launch import specs as S
@@ -267,7 +263,7 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
         mesh = make_mesh((4, 2), ("data", "model"))
         rules = rules_for_mesh(mesh)
         with axis_rules(rules, dict(zip(mesh.axis_names, mesh.devices.shape))), \\
-             compat.set_mesh(mesh):
+             jax.sharding.set_mesh(mesh):
             state = init_train_state(model, jax.random.PRNGKey(0))
             sh = S.train_state_shardings(mesh, jax.eval_shape(lambda: state))
             state = jax.device_put(state, sh)
@@ -276,7 +272,6 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
     """
     restore_code = f"""
         import numpy as np, jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_smoke_config
         from repro.distributed.partitioning import axis_rules, rules_for_mesh
         from repro.launch import specs as S
@@ -291,7 +286,7 @@ def test_elastic_restore_across_mesh_shapes(tmp_path):
         mesh = make_mesh((2, 4), ("data", "model"))  # DIFFERENT topology
         rules = rules_for_mesh(mesh)
         with axis_rules(rules, dict(zip(mesh.axis_names, mesh.devices.shape))), \\
-             compat.set_mesh(mesh):
+             jax.sharding.set_mesh(mesh):
             like = jax.eval_shape(
                 lambda: init_train_state(model, jax.random.PRNGKey(0)))
             sh = S.train_state_shardings(mesh, like)
@@ -316,7 +311,6 @@ def test_dryrun_cell_on_tiny_mesh():
     """The dry-run driver machinery on an 8-device (2,2,2) multi-pod mesh."""
     run_child("""
         import jax, jax.numpy as jnp
-        from repro import compat
         from repro.configs import get_smoke_config
         from repro.configs.base import ShapeSpec
         from repro.distributed.partitioning import axis_rules, rules_for_mesh
@@ -332,7 +326,7 @@ def test_dryrun_cell_on_tiny_mesh():
         sh = ShapeSpec("t", 128, 8, "train")
         model = build_model(cfg)
         with axis_rules(rules, dict(zip(mesh.axis_names, mesh.devices.shape))), \\
-             compat.set_mesh(mesh):
+             jax.sharding.set_mesh(mesh):
             st = S.train_state_shapes(model, cfg)
             lowered = jax.jit(
                 make_train_step(model, AdamWConfig(), grad_accum=2),

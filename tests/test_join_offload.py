@@ -13,6 +13,7 @@ XLA probe without changing results.
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import (
@@ -25,7 +26,8 @@ from repro.core import (
 )
 from repro.core import operators as ops
 from repro.core import planner
-from repro.kernels import ref
+from repro.core.requests import AggregateOp
+from repro.kernels import common, ref
 from repro.kernels import rme_join as KJ
 from repro.serve import QueryServer
 
@@ -343,6 +345,224 @@ def test_fallback_when_device_lowering_fails(table, build_table, monkeypatch):
     want = compile_plan(RelationalMemoryEngine(),
                         _join_plan(table, build_table)).run()
     _assert_join_equal(got, want)
+
+
+def test_lowering_failure_counts_kernel_fallbacks(table, build_table,
+                                                  monkeypatch):
+    """Every serve the XLA probe takes instead of the Pallas kernel is
+    counted — the counter a chip run checks to prove it timed the kernel."""
+    import repro.kernels.ops as kernel_ops
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("synthetic lowering failure")
+
+    eng = RelationalMemoryEngine(revision="mlp")
+    ops.clear_join_build_cache()
+    compile_plan(eng, _join_plan(table, build_table)).run()
+    assert eng.stats.kernel_fallbacks == 0  # healthy probe: the kernel ran
+    monkeypatch.setattr(kernel_ops, "hash_join", boom)
+    got = compile_plan(eng, _join_plan(table, build_table)).run()
+    assert eng.stats.kernel_fallbacks == 1
+    want = compile_plan(RelationalMemoryEngine(),
+                        _join_plan(table, build_table)).run()
+    _assert_join_equal(got, want)
+
+
+def test_scan_lowering_failure_counts_kernel_fallbacks(table, monkeypatch):
+    """The scan twin: a failed fused pass and a failed solo kernel each
+    count one fallback serve, and the xla revision never counts."""
+    import repro.kernels.ops as kernel_ops
+    from repro.kernels import rme_scan_multi
+
+    def boom(*args, **kwargs):
+        raise RuntimeError("synthetic lowering failure")
+
+    monkeypatch.setattr(rme_scan_multi, "scan_multi", boom)
+    monkeypatch.setattr(kernel_ops, "aggregate", boom)
+    eng = RelationalMemoryEngine(revision="mlp")
+    fused = [AggregateOp(table, "A1"), AggregateOp(table, "A3")]
+    got = eng.execute_many(fused)  # two requests: one fused pass
+    assert eng.stats.kernel_fallbacks == 1
+    eng.execute_many([AggregateOp(table, "A1")])  # solo kernel
+    assert eng.stats.kernel_fallbacks == 2
+    xla = RelationalMemoryEngine(revision="xla")
+    want = xla.execute_many(fused)
+    assert xla.stats.kernel_fallbacks == 0
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_probe_too_large_for_vmem_takes_counted_xla_route(
+        table, build_table, monkeypatch):
+    """A build side whose bucket arrays fit no row tile in the chip's VMEM
+    is decided before dispatch: the XLA probe serves it, counted, and the
+    kernel is never attempted."""
+    import repro.kernels.ops as kernel_ops
+
+    attempts = {"n": 0}
+    real = kernel_ops.hash_join
+
+    def counting(*args, **kwargs):
+        attempts["n"] += 1
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(kernel_ops, "hash_join", counting)
+    eng = RelationalMemoryEngine(revision="mlp")
+    ops.clear_join_build_cache()
+    compile_plan(eng, _join_plan(table, build_table)).run()  # warm the build
+    attempts["n"] = 0
+    # as compiled for a chip whose VMEM holds no tile of these buckets
+    monkeypatch.setattr(eng, "interpret", False)
+    monkeypatch.setattr(eng, "_vmem_budget", lambda words: 1)
+    got = compile_plan(eng, _join_plan(table, build_table)).run()
+    assert attempts["n"] == 0 and eng.stats.kernel_fallbacks == 1
+    want = compile_plan(RelationalMemoryEngine(),
+                        _join_plan(table, build_table)).run()
+    _assert_join_equal(got, want)
+
+
+def test_probe_vmem_guard_on_v5e():
+    """On a v5e's budget the bench-scale build side (P = 4096) probes at the
+    full row tile, while a 1M-row build side (P = 65536) fits no tile."""
+    limit = common.vmem_limit_bytes("TPU v5 lite")
+    eng = RelationalMemoryEngine()
+
+    def parts(p):
+        z = jnp.zeros((p, 19), jnp.int32)
+        return KJ.JoinPartitions(z, z, z, z)
+
+    assert eng._probe_block_rows(parts(4096), 18, limit) == eng.block_rows
+    assert eng._probe_block_rows(parts(65536), 18, limit) is None
+    with pytest.raises(ValueError, match="unknown TPU device kind"):
+        common.vmem_limit_bytes("TPU v99")
+
+
+def test_interpret_probe_sizes_tile_against_vmem_bytes(table, build_table):
+    """In interpret mode the probe's row tile is sized against the engine's
+    ``vmem_bytes``, the same budget as the fused pass: a tight budget halves
+    the tile, an absurd one keeps the floor tile — never a fallback, and
+    never a different result."""
+    ops.clear_join_build_cache()
+    roomy = RelationalMemoryEngine(revision="mlp")
+    want = compile_plan(roomy, _join_plan(table, build_table)).run()
+    assert roomy.stats.last_block_rows == roomy.block_rows
+    parts = roomy._build_join_partitions(build_table, "A2", "A3")
+    # row tiles of 2 or 18 words both pad to 128 lanes: one footprint
+    full = KJ.probe_vmem_footprint_bytes(parts, table.row_words,
+                                         roomy.block_rows)
+    for budget, tile in ((full - 1, roomy.block_rows // 2), (1, 32)):
+        eng = RelationalMemoryEngine(revision="mlp", vmem_bytes=budget)
+        got = compile_plan(eng, _join_plan(table, build_table)).run()
+        assert eng.stats.last_block_rows == tile
+        assert eng.stats.kernel_fallbacks == 0
+        _assert_join_equal(got, want)
+
+
+def test_vmem_budget_from_chip_holding_row_store(table):
+    """Compiled, both guards size against the VMEM of the chip that holds the
+    row store, and row ranges against its HBM, asked of the device once per
+    engine; an unknown chip raises rather than guess, and interpret mode
+    keeps ``vmem_bytes`` and never cuts row ranges."""
+
+    class Chip:
+        def __init__(self, kind):
+            self.device_kind = kind
+            self.asked = 0
+
+        def memory_stats(self):
+            return {"bytes_limit": 16_909_336_064}
+
+    class Words:
+        def __init__(self, chip):
+            self.chip = chip
+
+        def devices(self):
+            self.chip.asked += 1
+            return {self.chip}
+
+    eng = RelationalMemoryEngine(vmem_bytes=12345)
+    v5e = Chip("TPU v5 lite")
+    assert eng._vmem_budget(Words(v5e)) == 12345
+    assert eng._kernel_row_limit(Words(v5e), (18,), 256) is None
+    assert v5e.asked == 0
+    eng.interpret = False
+    want = common.vmem_limit_bytes("TPU v5 lite")
+    assert eng._vmem_budget(Words(v5e)) == want
+    assert eng._kernel_row_limit(Words(v5e), (18, 2), 256) == 1 << 21
+    assert eng._vmem_budget(Words(v5e)) == want and v5e.asked == 1
+    unknown = RelationalMemoryEngine()
+    unknown.interpret = False
+    with pytest.raises(ValueError, match="unknown TPU device kind"):
+        unknown._vmem_budget(Words(Chip("cpu")))
+
+
+def test_kernel_calls_split_into_row_ranges(table, build_table, monkeypatch):
+    """A table larger than one compiled kernel call may take is served in
+    row ranges — the fused pass, a solo kernel and both join probes (on the
+    fused pass's packed block, and streamed over the row store) — with the
+    results of one call."""
+    import repro.kernels.ops as kernel_ops
+    from repro.core import CompileOptions
+    from repro.kernels import rme_scan_multi
+
+    def run(eng):
+        server = QueryServer(eng)
+        tickets = [
+            server.submit(plan(table).project("A1", "A2")),
+            server.submit(plan(table).filter("A3", "gt", 10).project("A1")),
+            server.submit(plan(table).filter("A4", "lt", 0).sum("A1")),
+            server.submit(plan(table).groupby("A5", "A1", "sum", 8)),
+            server.submit(_join_plan(table, build_table)),
+        ]
+        server.drain()
+        solo_sum = compile_plan(
+            plan(table).filter("A4", "lt", 0).sum("A1"), eng).run()
+        solo_join = compile_plan(
+            _join_plan(table, build_table), eng,
+            options=CompileOptions(join_route="device-hash-join")).run()
+        return [t.result() for t in tickets] + [solo_sum, solo_join]
+
+    ops.clear_join_build_cache()
+    want = run(RelationalMemoryEngine(revision="mlp"))
+    calls = {"scan": 0, "solo": 0, "join": 0}
+
+    def counted(name, fn):
+        def wrapper(*args, **kwargs):
+            calls[name] += 1
+            return fn(*args, **kwargs)
+        return wrapper
+
+    monkeypatch.setattr(rme_scan_multi, "scan_multi",
+                        counted("scan", rme_scan_multi.scan_multi))
+    monkeypatch.setattr(kernel_ops, "aggregate",
+                        counted("solo", kernel_ops.aggregate))
+    monkeypatch.setattr(kernel_ops, "hash_join",
+                        counted("join", kernel_ops.hash_join))
+    monkeypatch.setattr(RelationalMemoryEngine, "_kernel_row_limit",
+                        lambda self, words, widths, block_rows: 128)
+    ops.clear_join_build_cache()
+    eng = RelationalMemoryEngine(revision="mlp")
+    got = run(eng)
+    pieces = -(-N_S // 128)
+    assert calls == {"scan": pieces, "solo": pieces, "join": 2 * pieces}
+    assert eng.stats.kernel_fallbacks == 0
+    for g, w in zip(got[:4] + got[5:6], want[:4] + want[5:6]):
+        for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    _assert_join_equal(got[4], want[4])
+    _assert_join_equal(got[6], want[6])
+
+
+def test_kernel_row_limit_on_v5e():
+    """The smoke's mixed pass (row store, a projection, a filter and its
+    mask) and its join probe take 2^20 rows per call on a v5e; the limit is
+    a power of two and never below a row tile."""
+    hbm = 16_909_336_064  # the bytes_limit a v5e reports
+    assert common.kernel_row_limit(hbm, (18, 2, 2, 1), 256) == 1 << 20
+    assert common.kernel_row_limit(hbm, (2, 1, 1, 1), 256) == 1 << 20
+    assert common.kernel_row_limit(hbm, (18, 2), 256) == 1 << 21
+    assert common.kernel_row_limit(hbm, (300,), 256) == 1 << 21
+    assert common.kernel_row_limit(1 << 16, (18,), 256) == 256
 
 
 def test_inexpressible_join_routes_to_host(table):

@@ -98,7 +98,6 @@ def test_collectives_detected_in_compiled_program():
     env["PYTHONPATH"] = os.path.join(repo, "src")
     code = textwrap.dedent("""
         import jax, jax.numpy as jnp
-        from repro import compat
         from jax.sharding import PartitionSpec as P
         from repro.launch.mesh import make_mesh
         from repro.roofline.analysis import compiled_hlo_text, hlo_stats
@@ -106,7 +105,7 @@ def test_collectives_detected_in_compiled_program():
         mesh = make_mesh((8,), ("data",))
         def f(x):
             return jax.lax.psum(x * 2, "data")
-        c = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=P("data"),
+        c = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
                                   out_specs=P())).lower(
             jax.ShapeDtypeStruct((1024,), jnp.float32)).compile()
         stats = hlo_stats(compiled_hlo_text(c))

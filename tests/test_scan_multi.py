@@ -411,6 +411,29 @@ def test_shared_step_fallback_isolates_the_offender():
     assert server.stats.served == 2 and server.stats.failed == 0
 
 
+def test_shared_step_fallback_is_counted():
+    """A shared pass that raised and was re-run query by query shows up in
+    ``shared_pass_fallbacks`` (and in ``snapshot()``)."""
+    _, t = make_table(n=100)
+    eng = RelationalMemoryEngine()
+    server = QueryServer(eng)
+    real = eng.execute_many
+
+    def flaky(ops):
+        if len(ops) > 1:
+            raise RuntimeError("fused pass failed to lower")
+        return real(ops)
+
+    eng.execute_many = flaky
+    server.submit(plan(t).project("A1", "A2"))
+    server.submit(plan(t).sum("A2"))
+    server.run_tick()
+    assert server.stats.served == 2
+    assert server.stats.shared_pass_fallbacks == 1
+    assert server.snapshot()["shared_pass_fallbacks"] == 1
+    assert server.snapshot()["engine_kernel_fallbacks"] == 0
+
+
 def test_mixed_kinds_two_tables_two_scans():
     _, t1 = make_table(n=300, seed=1)
     _, t2 = make_table(n=200, seed=2)
