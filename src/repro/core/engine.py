@@ -84,7 +84,7 @@ from repro.kernels import ops as K
 from repro.kernels import rme_scan_multi as KR
 from repro.kernels.rme_project import vmem_footprint_bytes
 
-from . import faults
+from . import faults, trace
 from .descriptor import bytes_moved
 from .ephemeral import EphemeralView
 from .requests import (AggregateOp, JoinOp, JoinResult, ProjectOp, ScanOp,
@@ -515,8 +515,12 @@ def _row_pieces(words: jax.Array, limit: int | None) -> list[jax.Array]:
     n = words.shape[0]
     if limit is None or n <= limit:
         return [words]
-    return [jax.lax.dynamic_slice_in_dim(words, start, min(limit, n - start))
-            for start in range(0, n, limit)]
+    pieces = []
+    for i, start in enumerate(range(0, n, limit)):
+        rows = min(limit, n - start)
+        with trace.span("engine.row_slice", range=i, rows=rows):
+            pieces.append(jax.lax.dynamic_slice_in_dim(words, start, rows))
+    return pieces
 
 
 # -------------------------------------------------- request subsumption
@@ -932,7 +936,9 @@ class RelationalMemoryEngine:
                 # the probe kernel streams the row-store chunks directly, and
                 # nothing crosses toward the CPU but the join result (a
                 # probe-side predicate needs the filtered packed route below)
-                results[entries[0][0]] = self._join_direct(ops[entries[0][0]])
+                with trace.span("engine.join_direct", table=tid):
+                    results[entries[0][0]] = self._join_direct(
+                        ops[entries[0][0]])
                 continue
             cover: dict = {}
             if self.subsume and len(reqs) > 1:
@@ -940,10 +946,14 @@ class RelationalMemoryEngine:
                 # predicate ⊇ a covering request's is served by deriving
                 # from the covering output, not by its own fused slot
                 reqs, cover = _cover_requests(reqs)
-            outs = self._serve_scan(table, reqs, shared=bool(cover))
+            with trace.span("engine.serve_scan", table=tid,
+                            requests=len(reqs)):
+                outs = self._serve_scan(table, reqs, shared=bool(cover))
             by_req = dict(zip(reqs, outs))
             for req, rep in cover.items():
-                by_req[req] = self._derive_covered(rep, req, by_req[rep])
+                with trace.span("engine.derive_covered", table=tid):
+                    by_req[req] = self._derive_covered(
+                        rep, req, by_req[rep])
             self.stats.subsumed_requests += len(cover)
             # a packed block consumed only by join probes stays on device —
             # bytes_to_cpu is charged only when a non-join consumer ships it
@@ -961,12 +971,15 @@ class RelationalMemoryEngine:
                     )
             for i, req in entries:
                 out = by_req[req]
-                results[i] = (self._finish_join(ops[i], out)
-                              if isinstance(ops[i], JoinOp)
-                              else finalize_scan_result(ops[i], out))
+                if isinstance(ops[i], JoinOp):
+                    with trace.span("engine.finish_join", table=tid):
+                        results[i] = self._finish_join(ops[i], out)
+                else:
+                    results[i] = finalize_scan_result(ops[i], out)
         return results
 
-    def execute_many_async(self, ops: Sequence[ScanOp]) -> PassHandle:
+    def execute_many_async(self, ops: Sequence[ScanOp], *,
+                           tick: int | None = None) -> PassHandle:
         """:meth:`execute_many` wrapped in a :class:`PassHandle`.
 
         Identical serving and accounting — one heterogeneous shared pass per
@@ -975,9 +988,12 @@ class RelationalMemoryEngine:
         caller may hold the handle across arbitrary host work (the pipelined
         serving tick compiles and launches tick N+1 while tick N's handle is
         outstanding).  Works unchanged on the sharded backend, whose
-        per-shard passes also enqueue without host syncs.
+        per-shard passes also enqueue without host syncs.  The pass is the
+        span ``engine.execute_many``; ``tick`` labels it with the serving
+        tick that sent it.
         """
-        return PassHandle(self.execute_many(ops))
+        with trace.span("engine.execute_many", tick=tick, ops=len(ops)):
+            return PassHandle(self.execute_many(ops))
 
     def materialize_many(self, views: Sequence[EphemeralView]) -> list[jax.Array]:
         """Materialize a batch of views with one shared scan per table.
@@ -1019,19 +1035,36 @@ class RelationalMemoryEngine:
         # a chunk larger than one kernel call may take is scanned in row
         # ranges, whose outputs combine like the chunks'
         per_chunk = [
-            self._scan_chunk(piece, reqs, block_rows, vmem, route)
-            for chunk in chunks
-            for piece in _row_pieces(chunk, self._kernel_row_limit(
-                chunk, _row_widths(chunk, reqs), block_rows))
+            out for c, chunk in enumerate(chunks)
+            for out in self._scan_ranges(c, chunk, reqs, block_rows, vmem,
+                                         route)
         ]
-        outs = (per_chunk[0] if len(per_chunk) == 1 else [
-            KR.combine_chunk_outputs(req, [o[r] for o in per_chunk])
-            for r, req in enumerate(reqs)
-        ])
+        if len(per_chunk) == 1:
+            outs = per_chunk[0]
+        else:
+            with trace.span("engine.combine", requests=len(reqs),
+                            ranges=len(per_chunk)):
+                outs = [KR.combine_chunk_outputs(req, [o[r] for o in per_chunk])
+                        for r, req in enumerate(reqs)]
         self.stats.shared_scans += 1
         self.stats.rows_projected += table.row_count
         for chunk in chunks:
             self.charge_scan(table, reqs, row_count=chunk.shape[0])
+        return outs
+
+    def _scan_ranges(self, c: int, chunk: jax.Array,
+                     reqs: tuple["KR.ScanRequest", ...], block_rows: int,
+                     vmem: int, route) -> list:
+        """Chunk ``c``'s fused pass, one kernel call (and span) per row
+        range.  The range slices die when this returns, before the caller
+        combines the outputs."""
+        outs = []
+        for i, piece in enumerate(_row_pieces(chunk, self._kernel_row_limit(
+                chunk, _row_widths(chunk, reqs), block_rows))):
+            with trace.span("engine.scan_multi", chunk=c, range=i,
+                            rows=piece.shape[0]):
+                outs.append(self._scan_chunk(piece, reqs, block_rows, vmem,
+                                             route))
         return outs
 
     def _scan_chunk(self, chunk: jax.Array,
@@ -1093,7 +1126,11 @@ class RelationalMemoryEngine:
             words, _row_widths(words, (req,)), self.block_rows))
         try:
             faults.maybe_fault("lowering", op="scan")
-            outs = [self._solo_kernel(piece, req) for piece in pieces]
+            outs = []
+            for i, piece in enumerate(pieces):
+                with trace.span("engine.scan_solo", range=i,
+                                rows=piece.shape[0]):
+                    outs.append(self._solo_kernel(piece, req))
         except Exception as err:
             if isinstance(err, faults.FaultError) and err.site != "lowering":
                 raise
@@ -1101,7 +1138,10 @@ class RelationalMemoryEngine:
             self.stats.kernel_fallbacks += 1
             return KR.scan_multi_xla(words, (req,))[0]
         self.breaker.record_success(route)
-        return outs[0] if len(outs) == 1 else KR.combine_chunk_outputs(req, outs)
+        if len(outs) == 1:
+            return outs[0]
+        with trace.span("engine.combine", requests=1, ranges=len(outs)):
+            return KR.combine_chunk_outputs(req, outs)
 
     def _solo_kernel(self, words: jax.Array, req: "KR.ScanRequest"):
         """Single-op kernel dispatch (bsl/pck revisions stay exercised)."""
@@ -1244,12 +1284,15 @@ class RelationalMemoryEngine:
             words, (words.shape[1], 1, 1, 1), block_rows))
         try:
             faults.maybe_fault("lowering", op="join")
-            outs = [K.hash_join(piece, partitions, key_word, val_word,
-                                ts_word=ts_word, ts=ts, build_ts=build_ts,
-                                revision=self.revision,
-                                block_rows=block_rows,
-                                interpret=self.interpret, vmem_limit=vmem)
-                    for piece in pieces]
+            outs = []
+            for i, piece in enumerate(pieces):
+                with trace.span("engine.hash_join", range=i,
+                                rows=piece.shape[0]):
+                    outs.append(K.hash_join(
+                        piece, partitions, key_word, val_word,
+                        ts_word=ts_word, ts=ts, build_ts=build_ts,
+                        revision=self.revision, block_rows=block_rows,
+                        interpret=self.interpret, vmem_limit=vmem))
         except Exception as err:
             if isinstance(err, faults.FaultError) and err.site != "lowering":
                 raise
@@ -1263,7 +1306,8 @@ class RelationalMemoryEngine:
             self.breaker.record_success(route)
         if len(outs) == 1:
             return outs[0]
-        return tuple(jnp.concatenate(col) for col in zip(*outs))
+        with trace.span("engine.combine", ranges=len(outs)):
+            return tuple(jnp.concatenate(col) for col in zip(*outs))
 
     def _chip_limits(self, words: jax.Array) -> tuple[int, int | None]:
         """``(scoped VMEM a compiled kernel may take, HBM bytes)`` of the chip

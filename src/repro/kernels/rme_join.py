@@ -282,6 +282,7 @@ def _hash_join(
         functools.partial(_probe_kernel, key_word, val_word, ts_word,
                           build_ts, n),
         grid=(n_pad // block_rows,),
+        name="rme_hash_join",
         in_specs=[
             pl.BlockSpec((block_rows, row_words), lambda i: (i, 0)),
             full, full, full, full,

@@ -313,6 +313,7 @@ def _scan_multi(
     return pl.pallas_call(
         functools.partial(_scan_multi_kernel, requests, n),
         grid=(n_pad // block_rows,),
+        name="rme_scan_multi",
         in_specs=[
             pl.BlockSpec((block_rows, row_words), lambda i: (i, 0)),
             pl.BlockSpec((n_req, 1), lambda i: (0, 0)),

@@ -119,9 +119,11 @@ EngineStats` plus its own :class:`ServerStats` — queue depth, shared-scan
 ratio, ``bytes_saved``, write counters, and per-lane :class:`LaneStats`:
 served/failed/deadline-miss counts, result bytes, and bounded
 :class:`LatencyReservoir` samples of total latency, queue wait, and service
-time, from which ``snapshot()`` exports p50/p95/p99 per lane.  See
-``docs/metrics.md`` for every counter's charging rule and
-``docs/serving.md`` for operating the loop under load.
+time, from which ``snapshot()`` exports p50/p95/p99 per lane.  Under a
+profiler session every tick, compile, launch and finalize is also a span
+(:mod:`repro.core.trace`) carrying the ticket's ``(tick, ticket)``.  See
+``docs/metrics.md`` for every counter's charging rule and the span tree,
+and ``docs/serving.md`` for operating the loop under load.
 """
 
 from __future__ import annotations
@@ -137,7 +139,7 @@ from typing import Any, Iterator, Mapping
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import faults
+from repro.core import faults, trace
 from repro.core.engine import RelationalMemoryEngine
 from repro.core.plan import PlanBuilder, PlanNode, Scan, decompose
 from repro.core.planner import (
@@ -236,7 +238,7 @@ class QueryTicket:
     """
 
     __slots__ = ("client", "lane", "deadline_s", "submitted_at", "admitted_at",
-                 "queue_wait_s", "latency_s", "route",
+                 "queue_wait_s", "latency_s", "route", "id", "tick",
                  "_event", "_result", "_error")
 
     def __init__(self, client: str, lane: str = "bulk",
@@ -249,6 +251,10 @@ class QueryTicket:
         self.queue_wait_s: float | None = None
         self.latency_s: float | None = None
         self.route: str | None = None
+        # stamped at admission: the server's count of admitted tickets, and
+        # the tick that drained it; every span of the request carries both
+        self.id: int | None = None
+        self.tick: int | None = None
         self._event = threading.Event()
         self._result: Any = None
         self._error: BaseException | None = None
@@ -345,10 +351,12 @@ class LaneStats:
 
     ``latency`` samples submit→resolve seconds; ``queue_wait`` the
     submit→drain share of it; ``service`` the remainder (compile + device +
-    finalize).  ``result_bytes`` sums each served op's own output size
-    (:meth:`~repro.core.requests.ProjectOp.result_bytes` and siblings; for
-    streams, the bytes actually pushed) — the lane's *output* volume,
-    distinct from the engine's bus-beat scan charges."""
+    finalize).  A ticket resolves when its result is enqueued, not when it
+    is ready, so for a bulk row output "resolve" is the enqueue: the device
+    may still be computing it.  ``result_bytes`` sums each served op's own
+    output size (:meth:`~repro.core.requests.ProjectOp.result_bytes` and
+    siblings; for streams, the bytes actually pushed) — the lane's *output*
+    volume, distinct from the engine's bus-beat scan charges."""
 
     served: int = 0
     failed: int = 0
@@ -451,6 +459,7 @@ class _InflightTick:
     are the launched bulk queries awaiting ``finish_tick``."""
 
     processed: int
+    tick: int = 0
     reads: list[_Admitted] = dataclasses.field(default_factory=list)
     compiled: list[PhysicalQuery | None] = dataclasses.field(default_factory=list)
     tokens: list[Any] = dataclasses.field(default_factory=list)
@@ -560,9 +569,6 @@ class QueryServer:
         # pinning is per-table: reads of never-written tables keep their
         # historical result shapes); touched only on the tick thread
         self._written_uids: set[int] = set()
-        # per-client running (count, sum_s, max_s) — scalars, not a sample
-        # list: a long-running server must not grow per served query
-        self._client_latency: dict[str, list[float]] = {}
         self._thread: threading.Thread | None = None
         self._stop = threading.Event()
 
@@ -721,6 +727,7 @@ class QueryServer:
             queue = self._express if adm.lane == "express" else self._bulk
             queue.append(adm)
             self.stats.submitted += 1
+            adm.ticket.id = self.stats.submitted
             if adm.write is not None:
                 self.stats.writes_submitted += 1
             self.stats.max_queue_depth = max(
@@ -784,14 +791,16 @@ class QueryServer:
         for req in batch:
             if req.write is None:
                 continue
-            try:
-                result = self._apply_write(req.write)
-            except Exception as e:
-                self._fail(req, e)
-                continue
-            self._written_uids.add(req.write.table.uid)
-            self.stats.writes_applied += 1
-            self._serve(req, result, route=f"write-{req.write.kind}")
+            with trace.span("server.writes", tick=req.ticket.tick,
+                            ticket=req.ticket.id, lane=req.lane):
+                try:
+                    result = self._apply_write(req.write)
+                except Exception as e:
+                    self._fail(req, e)
+                    continue
+                self._written_uids.add(req.write.table.uid)
+                self.stats.writes_applied += 1
+                self._serve(req, result, route=f"write-{req.write.kind}")
 
     # ------------------------------------------------------------ execution
     def _account_cold_groups(self, ops) -> None:
@@ -970,7 +979,10 @@ class QueryServer:
                 )
                 if snapshot_ts is not None:
                     base = dataclasses.replace(base, snapshot_ts=snapshot_ts)
-                pq = compile_plan(req.node, self.engine, options=base)
+                with trace.span("planner.compile_plan", tick=req.ticket.tick,
+                                ticket=req.ticket.id) as sp:
+                    pq = compile_plan(req.node, self.engine, options=base)
+                    sp.set_metadata(route=pq.route)
                 sig = self._plan_sig(req, pq)
                 if sig is not None and sig in self._poisoned:
                     compiled.append(None)
@@ -1003,7 +1015,8 @@ class QueryServer:
             ops.extend(pq.ops)
         self._account_cold_groups(ops)
         try:
-            handle = (self.engine.execute_many_async(ops) if ops else None)
+            handle = (self.engine.execute_many_async(
+                ops, tick=reads[0].ticket.tick) if ops else None)
         except Exception:
             # the shared step failed (one op's lowering error, OOM on the
             # union geometry, ...).  One bad client must not poison the
@@ -1036,13 +1049,15 @@ class QueryServer:
                 continue
             off, k = spans[i]
             try:
-                if pq.stream is not None:
-                    # eager call: snapshots the chunk list against THIS
-                    # tick's state, so a pipelined next tick's writes can't
-                    # leak into the stream drained at finish_tick
-                    tokens.append(pq.stream())
-                else:
-                    tokens.append(pq.launch(packed[off: off + k]))
+                with trace.span("server.launch", tick=req.ticket.tick,
+                                ticket=req.ticket.id, lane=req.lane):
+                    if pq.stream is not None:
+                        # eager call: snapshots the chunk list against THIS
+                        # tick's state, so a pipelined next tick's writes
+                        # can't leak into the stream drained at finish_tick
+                        tokens.append(pq.stream())
+                    else:
+                        tokens.append(pq.launch(packed[off: off + k]))
             except faults.TransientFault as e:
                 # a launch-time transient (e.g. a faulted upload): retry the
                 # query individually; either way it is settled here, so
@@ -1076,26 +1091,28 @@ class QueryServer:
                 continue
             if self._expire(req, "finalize"):
                 continue
-            try:
-                if pq.stream is not None:
-                    result = self._serve_stream(req, token)
-                else:
-                    result = pq.finalize(token)
-            except faults.TransientFault as e:
-                if pq.stream is not None:
-                    ok, result = self._retry_stream(req, pq, e)
-                else:
-                    # re-run the whole query individually: the launched
-                    # pass's tokens are tainted by the fault, a fresh
-                    # pq.run() is the clean per-query fallback path
-                    ok, result = self._retry_read(req, pq, e)
-                if not ok:
+            with trace.span("server.finalize", tick=req.ticket.tick,
+                            ticket=req.ticket.id, lane=req.lane):
+                try:
+                    if pq.stream is not None:
+                        result = self._serve_stream(req, token)
+                    else:
+                        result = pq.finalize(token)
+                except faults.TransientFault as e:
+                    if pq.stream is not None:
+                        ok, result = self._retry_stream(req, pq, e)
+                    else:
+                        # re-run the whole query individually: the launched
+                        # pass's tokens are tainted by the fault, a fresh
+                        # pq.run() is the clean per-query fallback path
+                        ok, result = self._retry_read(req, pq, e)
+                    if not ok:
+                        continue
+                except Exception as e:
+                    self._fail(req, e)
                     continue
-            except Exception as e:
-                self._fail(req, e)
-                continue
-            self._note_result_bytes(req, pq)
-            self._serve(req, result, route=pq.route)
+                self._note_result_bytes(req, pq)
+                self._serve(req, result, route=pq.route)
 
     def _serve_stream(self, req: _Admitted, chunk_iter) -> None:
         """Drain the query's chunk iterator (created at launch) into its
@@ -1134,6 +1151,12 @@ class QueryServer:
         if not batch:
             return None
         self.stats.ticks += 1
+        with trace.span("server.tick", tick=self.stats.ticks):
+            return self._begin_tick(batch, self.stats.ticks)
+
+    def _begin_tick(self, batch: list[_Admitted], tick_no: int) -> _InflightTick:
+        for req in batch:
+            req.ticket.tick = tick_no
         if self._open_ticks > 0:
             self.stats.ticks_overlapped += 1
         if self._poisoned:  # quarantine cooldowns tick down per served tick
@@ -1156,7 +1179,7 @@ class QueryServer:
         reads = express + bulk
         compiled = self._compile_reads(reads)
         tokens = self._launch_reads(reads, compiled)
-        tick = _InflightTick(processed=len(batch))
+        tick = _InflightTick(processed=len(batch), tick=tick_no)
         if tokens is not None:
             n = len(express)
             self._finalize_reads(reads[:n], compiled[:n], tokens[:n])
@@ -1178,15 +1201,16 @@ class QueryServer:
         tick.finished = True
         self._open_ticks -= 1
         if tick.reads:
-            # sweep deadlines BEFORE any O(rows) bulk transfer: a ticket
-            # that expired while its pass was in flight is resolved typed
-            # here and its finalize/transfer work is skipped entirely —
-            # the result is dropped, not pulled then discarded
-            for i, req in enumerate(tick.reads):
-                if (tick.compiled[i] is not None
-                        and self._expire(req, "finish_tick")):
-                    tick.compiled[i] = None
-            self._finalize_reads(tick.reads, tick.compiled, tick.tokens)
+            with trace.span("server.finish_tick", tick=tick.tick):
+                # sweep deadlines BEFORE any O(rows) bulk transfer: a ticket
+                # that expired while its pass was in flight is resolved
+                # typed here and its finalize/transfer work is skipped
+                # entirely — the result is dropped, not pulled then discarded
+                for i, req in enumerate(tick.reads):
+                    if (tick.compiled[i] is not None
+                            and self._expire(req, "finish_tick")):
+                        tick.compiled[i] = None
+                self._finalize_reads(tick.reads, tick.compiled, tick.tokens)
         return tick.processed
 
     def run_tick(self) -> int:
@@ -1216,11 +1240,6 @@ class QueryServer:
         if ticket.queue_wait_s is not None:
             lane.queue_wait.add(ticket.queue_wait_s)
             lane.service.add(max(lat - ticket.queue_wait_s, 0.0))
-        with self._lock:  # client_latencies() iterates under the lock
-            ent = self._client_latency.setdefault(ticket.client, [0, 0.0, 0.0])
-            ent[0] += 1
-            ent[1] += lat
-            ent[2] = max(ent[2], lat)
 
     def drain(self) -> int:
         """Run ticks until the admission queues are empty; returns total
@@ -1280,18 +1299,6 @@ class QueryServer:
         self.stop()
 
     # ------------------------------------------------------------ reporting
-    def client_latencies(self) -> dict[str, dict[str, float]]:
-        """Per-client latency summary: count / mean / max seconds."""
-        with self._lock:
-            return {
-                client: {
-                    "count": count,
-                    "mean_s": total / count,
-                    "max_s": max_s,
-                }
-                for client, (count, total, max_s) in self._client_latency.items()
-            }
-
     def snapshot(self) -> dict[str, Any]:
         """One flat dict of serving + engine counters (for logs/benchmarks).
 

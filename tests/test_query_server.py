@@ -158,9 +158,9 @@ def test_background_serving_thread(table):
         ]
         results = [tk.result(timeout=30) for tk in tickets]
     assert all(r is not None for r in results)
-    lat = server.client_latencies()
-    assert set(lat) == {"c0", "c1"}
-    assert all(v["count"] == 4 for v in lat.values())
+    assert server.stats.lanes["bulk"].latency.count == 8
+    assert sorted(tk.id for tk in tickets) == list(range(1, 9))
+    assert all(tk.tick >= 1 for tk in tickets)
     snap = server.snapshot()
     assert snap["served"] == 8 and snap["queue_depth"] == 0
     assert snap["max_latency_s"] >= snap["mean_latency_s"] > 0
