@@ -13,6 +13,8 @@ built from the same seed (independent of ``src/repro``).
   and a device hash join.
 * Tick 2: an insert of 4,096 rows, an update and a delete, then the same
   reads again under the tick's MVCC snapshot.
+* The join probe kernel alone on full-range int32 keys, payloads and
+  timestamps, at the smoke's bucket capacity and with one crowded bucket.
 
 Exact equality is required for projections, filter blocks and masks, join
 outputs and the filtered count.  Sums and averages accumulate in float32 on the device
@@ -327,6 +329,56 @@ def check_counters(chk: Checker, label: str, server) -> None:
         chk.record(f"{label}/{k}", v == 0, f"{k} = {v}, want 0")
 
 
+def check_join_words(chk: Checker, seed: int, dev) -> None:
+    """The join probe kernel alone on full-range int32 words: keys,
+    payloads and build timestamps drawn over all 32 bits (the relation's
+    [-1000, 1000) values leave the high bytes all zeros or all ones), at
+    the smoke's bucket capacity and with one crowded bucket, unpinned and
+    pinned.  Every output must equal a numpy oracle bit for bit."""
+    from repro.kernels import common
+    from repro.kernels import rme_join as KJ
+
+    i32 = np.iinfo(np.int32)
+    rng = np.random.default_rng(seed + 1)
+    limit = (common.vmem_limit_bytes(dev.device_kind)
+             if dev.platform == "tpu" else None)
+
+    def words(n, low=i32.min, high=i32.max):
+        return rng.integers(low, high, n, dtype=np.int64).astype(np.int32)
+
+    ts = 5
+    for crowd in (0, 48):
+        key = np.unique(words(BUILD_ROWS))
+        p = KJ.num_buckets_for(key.shape[0])
+        if crowd:  # extra keys that all hash to bucket 7
+            pool = np.setdiff1d(words(1 << 22), key)
+            key = np.concatenate(
+                [key, pool[KJ.bucket_of_np(pool, p) == 7][:crowd]])
+        m = key.shape[0]
+        val = words(m)
+        begin = np.where(rng.random(m) < 0.7, words(m, high=ts + 1),
+                         words(m, low=ts + 1))
+        end = np.where(rng.random(m) < 0.3, words(m, high=ts + 1),
+                       words(m, low=ts + 1))
+        parts = KJ.build_partitions(key, val, begin, end)
+        s_key = np.where(rng.random(1 << 18) < 0.5, rng.choice(key, 1 << 18),
+                         words(1 << 18))
+        s_val = words(1 << 18)
+        order = np.argsort(key)
+        at = np.minimum(np.searchsorted(key[order], s_key), m - 1)
+        hit = key[order][at] == s_key
+        probe = np.stack([s_val, s_key], axis=1)
+        for pinned in (False, True):
+            want_m = hit & (((begin <= ts) & (ts < end))[order][at]
+                            if pinned else True)
+            want = (s_val, np.where(want_m, val[order][at], 0), want_m)
+            got = KJ.hash_join(probe, parts, 1, 0, ts=ts, build_ts=pinned,
+                               vmem_limit=limit)
+            tag = f"join_words/C{parts.capacity}/{'pinned' if pinned else 'unpinned'}"
+            for name, g, w in zip(("s_proj", "r_proj", "matched"), got, want):
+                chk.exact(f"{tag}.{name}", g, w)
+
+
 # ------------------------------------------------------------------ paths
 def one_chip(args, n: int, chk: Checker, dev) -> None:
     from repro.core import RelationalMemoryEngine, RelationalTable, benchmark_schema
@@ -356,6 +408,7 @@ def one_chip(args, n: int, chk: Checker, dev) -> None:
         if key in stats:
             print(f"device {key}: {stats[key]}", flush=True)
     check_counters(chk, "1chip", server)
+    check_join_words(chk, args.seed, dev)
 
 
 def four_chips(args, n: int, chk: Checker) -> None:

@@ -148,7 +148,7 @@ class EngineStats:
       ``fig_fault_recovery`` relies on that.
     * ``kernel_fallbacks`` — serves a Pallas revision sent to the XLA
       fallback instead of its kernel: a failed kernel dispatch, a route the
-      circuit breaker holds open, or a join probe whose bucket arrays do
+      circuit breaker holds open, or a join probe whose plane arrays do
       not fit the chip's VMEM.  Zero whenever every request of a Pallas
       revision ran on its kernel; the ``xla`` revision never counts here.
     """
@@ -1258,7 +1258,7 @@ class RelationalMemoryEngine:
         Pallas grid pass when the revision supports it, else — or on any
         lowering error — the fused-gather XLA probe (same results).  Before
         dispatch the row tile is halved until the modeled working set (row
-        tile + resident bucket arrays) fits :meth:`_vmem_budget`; on a chip a
+        tile + resident plane arrays) fits :meth:`_vmem_budget`; on a chip a
         build side too large for any tile takes the XLA probe, counted in
         ``kernel_fallbacks`` like every other fallback serve.  ``route``
         threads the caller's circuit-breaker key so repeated lowering
@@ -1271,7 +1271,8 @@ class RelationalMemoryEngine:
         if self.revision == "xla":
             return xla_probe()
         vmem = self._vmem_budget(words)
-        block_rows = self._probe_block_rows(partitions, words.shape[1], vmem)
+        block_rows = self._probe_block_rows(partitions, words.shape[1], vmem,
+                                            build_ts)
         if block_rows is None and self.interpret:
             # the interpreter has no VMEM to exhaust: the floor tile serves
             block_rows = MIN_FUSED_BLOCK_ROWS
@@ -1287,7 +1288,8 @@ class RelationalMemoryEngine:
             outs = []
             for i, piece in enumerate(pieces):
                 with trace.span("engine.hash_join", range=i,
-                                rows=piece.shape[0]):
+                                rows=piece.shape[0],
+                                lanes=partitions.kv_planes.shape[1]):
                     outs.append(K.hash_join(
                         piece, partitions, key_word, val_word,
                         ts_word=ts_word, ts=ts, build_ts=build_ts,
@@ -1340,14 +1342,14 @@ class RelationalMemoryEngine:
             return None
         return common.kernel_row_limit(hbm, widths, block_rows)
 
-    def _probe_block_rows(self, partitions, row_words: int,
-                          vmem: int) -> int | None:
+    def _probe_block_rows(self, partitions, row_words: int, vmem: int,
+                          build_ts: bool = False) -> int | None:
         """The largest row tile (halving from ``block_rows``, never below
         ``MIN_FUSED_BLOCK_ROWS``) whose modeled probe working set fits
         ``vmem``; ``None`` when even the smallest tile does not."""
         block_rows = self.block_rows
-        while (K.probe_vmem_footprint_bytes(partitions, row_words, block_rows)
-               > vmem):
+        while K.probe_vmem_footprint_bytes(partitions, row_words, block_rows,
+                                           build_ts) > vmem:
             if block_rows // 2 < MIN_FUSED_BLOCK_ROWS:
                 return None
             block_rows //= 2

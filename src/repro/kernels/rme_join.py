@@ -20,12 +20,17 @@ module moves the probe itself next to the data:
   row (``s_proj``, ``r_proj``) plus a ``matched`` validity mask.
 
 TPU adaptation: buckets are selected with a one-hot MXU contraction (the
-``groupby_sum`` idiom), not a gather.  Because float32 matmuls are only exact
-to 2^24, every int32 bucket column travels as two exact 16-bit halves through
-the contraction and is recombined bitwise afterwards — bit-exact selection on
-the MXU, no dynamic indexing in the kernel.  The contraction asks for
-``Precision.HIGHEST``: at the TPU's default precision the halves would pass
-through bfloat16 (8 significant bits) and lose their low bits.
+``groupby_sum`` idiom), not a gather.  Every int32 bucket word also travels
+as four **byte planes** — byte ``j`` of each word, 0..255, exact in bfloat16's
+8 significant bits — laid side by side in one ``(P, K)`` bfloat16 array per
+pair of columns (``kv_planes``: key and payload; ``ts_planes``: begin and
+end).  The one-hot is exactly 0 or 1, so a single default-precision bf16 ×
+bf16 → float32 pass selects every byte exactly (one nonzero term per output,
+at most 255); a second, eight times smaller pass pairs the bytes into exact
+16-bit halves on whole 128-lane tiles, and shifts and ORs rebuild the
+original bit pattern — bit-exact selection at the MXU's single-pass rate, no
+dynamic indexing in the kernel.  ``K`` is ``8·C`` padded to whole 128-lane
+tiles: it follows the observed capacity.
 
 The bucket hash is **Fibonacci multiplicative hashing**: ``bucket = (key *
 2654435761) >>> (32 - log2 P)`` (the top bits of the wrapped product, same
@@ -46,7 +51,7 @@ columns let the same snapshot test run against the *build* rows — one cached
 partition set serves any snapshot time, because ``ts`` is a traced operand.
 
 ``hash_join_xla`` is the fused-gather fallback (plain ``jnp.take`` bucket
-lookup) used for the ``xla`` revision, for a build side whose bucket arrays
+lookup) used for the ``xla`` revision, for a build side whose plane arrays
 do not fit the chip's VMEM (decided before dispatch from
 :func:`probe_vmem_footprint_bytes`), and as the per-query escape when the
 Pallas probe fails to lower, mirroring ``scan_multi_xla``.
@@ -77,20 +82,24 @@ MIX_INT32 = np.int32(np.uint32(2654435761).astype(np.int64) - (1 << 32))
 
 
 class JoinPartitions(NamedTuple):
-    """The build side as static device buckets: four ``(P, C)`` int32 arrays.
+    """The build side as static device buckets: four ``(P, C)`` int32 arrays
+    and their byte planes, two ``(P, K)`` bfloat16 arrays.
 
     A NamedTuple of arrays on purpose — the planner's join build cache
     accounts entry bytes by iterating the entry, exactly as it does for the
     sorted-index tuples it already holds.  Empty ``keys`` slots hold a fill
     that provably hashes to a *different* bucket (see :func:`bucket_fills`),
     so they can never false-match; their ``begin=1, end=0`` timestamps are
-    never visible at any snapshot either.
+    never visible at any snapshot either.  The int32 arrays feed the XLA
+    probe (:func:`hash_join_xla`), the planes the Pallas probe.
     """
 
     keys: jax.Array  # (P, C) raw int32 key words
     vals: jax.Array  # (P, C) raw int32 payload words
     begin: jax.Array  # (P, C) __ts_begin of each build row
     end: jax.Array  # (P, C) __ts_end of each build row
+    kv_planes: jax.Array  # (P, K) bf16 bytes 0..3 of keys, then of vals
+    ts_planes: jax.Array  # (P, K) bf16 bytes 0..3 of begin, then of end
 
     @property
     def num_buckets(self) -> int:
@@ -140,13 +149,53 @@ def bucket_fills(p: int) -> np.ndarray:
     return fills
 
 
+def _pad(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def plane_lanes(capacity: int) -> int:
+    """Lanes ``K`` of a byte-plane array: eight planes of ``C`` slots side
+    by side, padded to whole 128-lane tiles."""
+    return _pad(8 * capacity, 128)
+
+
 def estimated_partition_bytes(n_rows: int) -> int:
     """Planner-side estimate of a build table's partition-array bytes (four
-    ``(P, C)`` int32 arrays at the target load) — the build-upload term of
-    the join route cost model, available before anything is built."""
+    ``(P, C)`` int32 arrays and two ``(P, K)`` bfloat16 plane arrays at the
+    target load) — the build-upload term of the join route cost model,
+    available before anything is built."""
     p = num_buckets_for(n_rows)
     c = max(1, -(-n_rows // p))
-    return 4 * p * c * 4
+    return 4 * p * c * 4 + 2 * p * plane_lanes(c) * 2
+
+
+def _byte_planes(a: np.ndarray, b: np.ndarray) -> jax.Array:
+    """Two ``(P, C)`` int32 columns as one ``(P, K)`` bfloat16 array: lanes
+    ``[q·C, (q+1)·C)`` hold plane ``q = 4w + j``, byte ``j`` of column
+    ``w``."""
+    p, c = a.shape
+    u = np.stack([a, b]).view(np.uint32)[:, None]  # (2, 1, P, C)
+    shifts = np.arange(0, 32, 8, dtype=np.uint32)[None, :, None, None]
+    planes = (u >> shifts) & np.uint32(0xFF)  # (2, 4, P, C)
+    out = np.zeros((p, plane_lanes(c)), np.float32)
+    out[:, : 8 * c] = planes.transpose(2, 0, 1, 3).reshape(p, 8 * c)
+    return jnp.asarray(out.astype(jnp.bfloat16))
+
+
+@functools.lru_cache(maxsize=None)
+def _pair_weights(capacity: int) -> np.ndarray:
+    """``(K, 4·Cp)`` bfloat16 matrix, ``Cp = C`` padded to 128 lanes, that
+    pairs selected byte planes into 16-bit halves: slot ``s`` of plane ``q``
+    goes to lane ``s`` of half ``q // 2``, times 256 for odd ``q``.  Half
+    ``2w`` holds the low 16 bits of column ``w``, half ``2w + 1`` the high,
+    each on whole 128-lane tiles."""
+    c, cp = capacity, _pad(capacity, 128)
+    q, s = np.divmod(np.arange(8 * c), c)
+    w = np.zeros((plane_lanes(c), 4 * cp), np.float32)
+    w[np.arange(8 * c), cp * (q // 2) + s] = np.where(q % 2, 256.0, 1.0)
+    w = w.astype(jnp.bfloat16)
+    w.flags.writeable = False
+    return w
 
 
 def build_partitions(
@@ -178,74 +227,76 @@ def build_partitions(
     slot = np.arange(n, dtype=np.int64) - np.repeat(starts, counts)
     gb, sb = g[order], slot
 
-    def scatter(fill: np.ndarray, values: np.ndarray) -> jax.Array:
+    def scatter(fill: np.ndarray, values: np.ndarray) -> np.ndarray:
         arr = np.broadcast_to(fill[:, None], (p, cap)).copy()
         arr[gb, sb] = values[order]
-        return jnp.asarray(arr)
+        return arr
 
+    keys = scatter(bucket_fills(p), key)  # fills provably never match
+    vals = scatter(np.zeros(p, np.int32), val)
+    begin = scatter(np.ones(p, np.int32),
+                    np.zeros(n, np.int32) if ts_begin is None
+                    else np.asarray(ts_begin, dtype=np.int32))
+    end = scatter(np.zeros(p, np.int32),
+                  np.zeros(n, np.int32) if ts_end is None
+                  else np.asarray(ts_end, dtype=np.int32))
     return JoinPartitions(
-        keys=scatter(bucket_fills(p), key),  # fills provably never match
-        vals=scatter(np.zeros(p, np.int32), val),
-        begin=scatter(np.ones(p, np.int32),
-                      np.zeros(n, np.int32) if ts_begin is None
-                      else np.asarray(ts_begin, dtype=np.int32)),
-        end=scatter(np.zeros(p, np.int32),
-                    np.zeros(n, np.int32) if ts_end is None
-                    else np.asarray(ts_end, dtype=np.int32)),
+        keys=jnp.asarray(keys), vals=jnp.asarray(vals),
+        begin=jnp.asarray(begin), end=jnp.asarray(end),
+        kv_planes=_byte_planes(keys, vals),
+        ts_planes=_byte_planes(begin, end),
     )
 
 
 # ------------------------------------------------------------ Pallas probe
-def _split16(words: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """int32 -> two float32 halves, each exactly representable (< 2^16)."""
-    hi = jax.lax.shift_right_logical(words, 16).astype(jnp.float32)
-    lo = (words & 0xFFFF).astype(jnp.float32)
-    return hi, lo
+def _select_words(onehot: jax.Array, planes: jax.Array,
+                  weights: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Bit-exact per-row bucket selection on the MXU, two bfloat16 passes
+    with float32 results: ``(B, P) @ (P, K)`` selects every byte (one
+    nonzero term per output, at most 255), then ``(B, K) @ (K, 4·Cp)`` pairs
+    the bytes into 16-bit halves (``b_lo + 256·b_hi < 2^16``, one or two
+    nonzero terms).  Shifts and ORs rebuild both columns' int32 words,
+    ``(B, Cp)`` each; slots at and above ``C`` read 0.
+
+    The halves land on whole 128-lane tiles on purpose: slicing bytes at
+    unaligned lane offsets and shifting them into place compiled to wrong
+    bits on a v5e, while the pairing pass is exact there."""
+    sel = jnp.dot(onehot, planes, preferred_element_type=jnp.float32)
+    halves = jnp.dot(sel.astype(jnp.bfloat16), weights,
+                     preferred_element_type=jnp.float32)
+    cp = halves.shape[1] // 4
+    h = [halves[:, cp * t : cp * (t + 1)].astype(jnp.int32) for t in range(4)]
+    return (h[1] << 16) | h[0], (h[3] << 16) | h[2]
 
 
-def _merge16(hi: jax.Array, lo: jax.Array) -> jax.Array:
-    """Recombine the exact halves into the original int32 bit pattern."""
-    return (hi.astype(jnp.int32) << 16) | lo.astype(jnp.int32)
-
-
-def _onehot_select(onehot: jax.Array, bucket_words: jax.Array) -> jax.Array:
-    """Bit-exact per-row bucket selection on the MXU: ``(B, P) @ (P, C)``
-    contractions over the two 16-bit halves, recombined bitwise."""
-    hi, lo = _split16(bucket_words)
-    dims = (((1,), (0,)), ((), ()))
-    sel_hi = jax.lax.dot_general(onehot, hi, dims,
-                                 precision=jax.lax.Precision.HIGHEST,
-                                 preferred_element_type=jnp.float32)
-    sel_lo = jax.lax.dot_general(onehot, lo, dims,
-                                 precision=jax.lax.Precision.HIGHEST,
-                                 preferred_element_type=jnp.float32)
-    return _merge16(sel_hi, sel_lo)
-
-
-def _probe_kernel(key_word, val_word, ts_word, build_ts, n_rows,
-                  x_ref, bk_ref, bv_ref, bb_ref, be_ref, ts_ref,
-                  s_ref, r_ref, m_ref):
+def _probe_kernel(key_word, val_word, ts_word, build_ts, capacity, n_rows,
+                  x_ref, w_ref, kv_ref, *refs):
+    ts_planes_ref = refs[0] if build_ts else None
+    ts_ref, s_ref, r_ref, m_ref = refs[-4:]
     i = pl.program_id(0)
     block_rows = x_ref.shape[0]
-    p = bk_ref.shape[0]
+    p = kv_ref.shape[0]
     s_key = x_ref[:, key_word]
     g = _bucket_of(s_key, p)
     onehot = (
         g[:, None] == jax.lax.iota(jnp.int32, p)[None, :]
-    ).astype(jnp.float32)  # (B, P)
-    match = _onehot_select(onehot, bk_ref[...]) == s_key[:, None]  # (B, C)
+    ).astype(jnp.bfloat16)  # (B, P), exact
+    weights = w_ref[...]
+    keys, vals = _select_words(onehot, kv_ref[...], weights)  # (B, Cp) each
+    # padding slots read key 0, a word a probe row may hold
+    slot = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
+    match = (keys == s_key[:, None]) & (slot < capacity)
     ts = ts_ref[0, 0]
     if build_ts:
-        match = match & (_onehot_select(onehot, bb_ref[...]) <= ts)
-        match = match & (ts < _onehot_select(onehot, be_ref[...]))
+        begin, end = _select_words(onehot, ts_planes_ref[...], weights)
+        match = match & (begin <= ts) & (ts < end)
     ridx = tile_row_ids(i, block_rows)
     valid = ridx < n_rows
     if ts_word >= 0:
         valid = valid & (x_ref[:, ts_word] <= ts) & (ts < x_ref[:, ts_word + 1])
     matched = jnp.any(match, axis=1) & valid
-    r_val = jnp.sum(
-        jnp.where(match, _onehot_select(onehot, bv_ref[...]), 0), axis=1
-    )  # primary-key build side: at most one slot matches
+    # primary-key build side: at most one slot matches
+    r_val = jnp.sum(jnp.where(match, vals, 0), axis=1)
     s_ref[...] = jnp.where(valid, x_ref[:, val_word], 0)[:, None]
     r_ref[...] = jnp.where(matched, r_val, 0)[:, None]
     m_ref[...] = matched[:, None].astype(jnp.int32)
@@ -254,19 +305,18 @@ def _probe_kernel(key_word, val_word, ts_word, build_ts, n_rows,
 @functools.partial(
     jax.jit,
     static_argnames=("key_word", "val_word", "ts_word", "build_ts",
-                     "block_rows", "interpret", "vmem_limit"),
+                     "capacity", "block_rows", "interpret", "vmem_limit"),
 )
 def _hash_join(
     words: jax.Array,
-    bk: jax.Array,
-    bv: jax.Array,
-    bb: jax.Array,
-    be: jax.Array,
+    kv_planes: jax.Array,
+    ts_planes: jax.Array | None,  # read only when ``build_ts``
     ts_arr: jax.Array,  # (1, 1) int32 traced snapshot time
     key_word: int,
     val_word: int,
     ts_word: int,
     build_ts: bool,
+    capacity: int,
     block_rows: int,
     interpret: bool | None,
     vmem_limit: int | None,
@@ -274,18 +324,20 @@ def _hash_join(
     n, row_words = words.shape
     x = pad_rows(words, block_rows)
     n_pad = x.shape[0]
-    p, c = bk.shape
-    full = pl.BlockSpec((p, c), lambda i: (0, 0))
+    full = pl.BlockSpec(kv_planes.shape, lambda i: (0, 0))
+    planes = (kv_planes, ts_planes) if build_ts else (kv_planes,)
+    weights = jnp.asarray(_pair_weights(capacity))
     col = pl.BlockSpec((block_rows, 1), lambda i: (i, 0))
     out_shape = jax.ShapeDtypeStruct((n_pad, 1), jnp.int32)
     return pl.pallas_call(
         functools.partial(_probe_kernel, key_word, val_word, ts_word,
-                          build_ts, n),
+                          build_ts, capacity, n),
         grid=(n_pad // block_rows,),
         name="rme_hash_join",
         in_specs=[
             pl.BlockSpec((block_rows, row_words), lambda i: (i, 0)),
-            full, full, full, full,
+            pl.BlockSpec(weights.shape, lambda i: (0, 0)),
+            *(full for _ in planes),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=[col, col, col],
@@ -293,7 +345,7 @@ def _hash_join(
         compiler_params=(None if vmem_limit is None else
                          pltpu.CompilerParams(vmem_limit_bytes=vmem_limit)),
         interpret=resolve_interpret(interpret),
-    )(x, bk, bv, bb, be, ts_arr)
+    )(x, weights, *planes, ts_arr)
 
 
 def hash_join(
@@ -321,7 +373,7 @@ def hash_join(
     traced operand: distinct snapshot times never retrace.  Rows are
     position-local, so per-chunk outputs concatenate (the
     ``scan_multi_chunked`` contract).  ``vmem_limit`` raises the compiled
-    kernel's scoped-VMEM limit (the bucket arrays stay resident); size it
+    kernel's scoped-VMEM limit (the plane arrays stay resident); size it
     with :func:`probe_vmem_footprint_bytes` before dispatch.
     """
     if revision == "xla":
@@ -330,9 +382,10 @@ def hash_join(
     ts_arr = jnp.asarray([[ts]], dtype=jnp.int32)
     n = words.shape[0]
     s, r, m = _hash_join(
-        words, *partitions, ts_arr, key_word=key_word, val_word=val_word,
-        ts_word=ts_word, build_ts=build_ts, block_rows=block_rows,
-        interpret=interpret, vmem_limit=vmem_limit,
+        words, partitions.kv_planes, partitions.ts_planes if build_ts else None,
+        ts_arr, key_word=key_word, val_word=val_word, ts_word=ts_word,
+        build_ts=build_ts, capacity=partitions.capacity,
+        block_rows=block_rows, interpret=interpret, vmem_limit=vmem_limit,
     )
     return s[:n, 0], r[:n, 0], m[:n, 0].astype(bool)
 
@@ -377,38 +430,38 @@ def hash_join_xla(
     Lowers anywhere; the ``xla`` revision and per-query lowering-failure
     fallback both dispatch here."""
     ts_arr = jnp.asarray([[ts]], dtype=jnp.int32)
-    return _hash_join_xla(words, *partitions, ts_arr, key_word=key_word,
+    return _hash_join_xla(words, partitions.keys, partitions.vals,
+                          partitions.begin, partitions.end, ts_arr,
+                          key_word=key_word,
                           val_word=val_word, ts_word=ts_word,
                           build_ts=build_ts)
 
 
-def _pad(n: int, m: int) -> int:
-    return -(-n // m) * m
-
-
 def probe_vmem_footprint_bytes(
     partitions: JoinPartitions, row_words: int,
-    block_rows: int = DEFAULT_BLOCK_ROWS,
+    block_rows: int = DEFAULT_BLOCK_ROWS, build_ts: bool = False,
 ) -> int:
     """Modeled VMEM working set of one compiled probe grid step, in bytes.
 
     Every VMEM array is tiled ``(8, 128)``, so narrow minor dimensions are
     charged at 128 lanes: the double-buffered row tile and three ``(B, 1)``
-    output columns, the four double-buffered ``(P, C)`` bucket arrays, the
-    ``(B, P)`` float32 one-hot plus its three bfloat16 pieces (what a
-    ``Precision.HIGHEST`` contraction splits it into), and one bucket
-    array's 16-bit halves with their pieces.  An upper bound of what Mosaic
-    allocates, checked against compiles for a v5e in
-    ``tests/test_tpu_compile.py``.
+    output columns, the double-buffered ``(P, K)`` bfloat16 plane arrays
+    (two with ``build_ts``) and ``(K, 4·Cp)`` pairing weights, the int32
+    compare and bfloat16 one-hot ``(B, P)``, and per plane array the
+    float32 ``(B, K)`` bytes, their bfloat16 copy and the float32 ``(B,
+    4·Cp)`` halves.  An upper bound of what Mosaic allocates, checked
+    against compiles for a v5e in ``tests/test_tpu_compile.py``.
     """
+    n_planes = 2 if build_ts else 1
     b = _pad(block_rows, 8)
     p = _pad(partitions.num_buckets, 8)
-    c = _pad(partitions.capacity, 128)
+    k = partitions.kv_planes.shape[1]
+    halves = 4 * _pad(partitions.capacity, 128)
     tiles = 2 * b * (_pad(row_words, 128) + 3 * 128) * 4
-    buckets = 4 * 2 * p * c * 4
-    onehot = b * p * (4 + 3 * 2)
-    halves = 2 * p * c * (4 + 3 * 2) + 2 * b * c * 4
-    return tiles + buckets + onehot + halves
+    planes = 2 * (n_planes * p * k + k * halves) * 2
+    onehot = b * p * (4 + 2)
+    selected = n_planes * b * (k * (4 + 2) + halves * 4)
+    return tiles + planes + onehot + selected
 
 
 def broadcast_partitions(

@@ -135,6 +135,90 @@ def test_partition_invariants():
         assert (KJ.bucket_of_np(f, pb) != np.arange(pb)).all()
 
 
+I32 = np.iinfo(np.int32)
+# words whose bytes cover every edge: sign bit, all ones, zero, alternating
+# bytes, and values above 2^24 that a float32 cannot hold
+EDGE_WORDS = np.array([I32.min, I32.max, -1, 0, 1, 0x00FF00FF, -0x00FF0100,
+                       (1 << 24) + 1, -(1 << 24) - 3, 0x7F00FF01], np.int32)
+EXACT_CASES = [("edges", False), ("edges", True), ("fills", False),
+               ("fills", True), ("wide", False), ("wide", True),
+               ("versions", True)]
+
+
+def _exact_build_side(case, rng):
+    """``(key, val, begin, end)`` of a build side for one exactness case;
+    keys are unique except in ``versions``, MVCC version triples of which at
+    most one is visible at ts = 10."""
+    rand = rng.integers(I32.min, I32.max, 400, dtype=np.int64).astype(np.int32)
+    if case == "edges":
+        key = np.unique(np.concatenate([EDGE_WORDS, rand[:200]]))
+    elif case == "fills":  # the fill words 0 and 1 are never build keys
+        key = np.setdiff1d(rand[:200], [0, 1])
+    elif case == "wide":  # 48 distinct keys crafted to collide in one bucket
+        p = KJ.num_buckets_for(200)
+        pool = rng.integers(I32.min, I32.max, 1 << 16, dtype=np.int64)
+        crowd = np.unique(pool[KJ.bucket_of_np(pool, p) == 3].astype(np.int32))
+        rest = np.setdiff1d(rand, crowd)
+        rest = rest[KJ.bucket_of_np(rest, p) != 3][:152]
+        key = np.concatenate([crowd[:48], rest])
+    else:  # versions
+        key = np.repeat(np.unique(np.concatenate([EDGE_WORDS, rand[:90]])), 3)
+    n = key.shape[0]
+    val = np.resize(np.concatenate([EDGE_WORDS[::-1], rand[200:]]), n)
+    if case == "versions":
+        # each key's versions live in [0, 8), [8, 12), [12, 20): only the
+        # middle one is visible at ts = 10, unless a random end cuts it
+        begin = np.tile(np.array([0, 8, 12], np.int32), n // 3)
+        end = np.tile(np.array([8, 12, 20], np.int32), n // 3)
+        end[1::3] = np.where(rng.random(n // 3) < 0.2, 9, 12)
+    else:
+        begin = rng.integers(0, 20, n).astype(np.int32)
+        end = begin + rng.integers(0, 20, n).astype(np.int32)
+    return key.astype(np.int32), val.astype(np.int32), begin, end
+
+
+@pytest.mark.parametrize("case, build_ts", EXACT_CASES,
+                         ids=[f"{c}-{'ts' if t else 'nots'}"
+                              for c, t in EXACT_CASES])
+def test_byte_plane_probe_is_bit_exact(case, build_ts):
+    """The Pallas probe selects bucket words through bfloat16 byte planes:
+    every int32 key, payload and timestamp word must come back bit-exact —
+    edge words, words above 2^24, the bucket fills, and a crowded bucket
+    whose 8·C planes span more than one 128-lane tile."""
+    rng = np.random.default_rng(sum(map(ord, case)) + build_ts)
+    key, val, begin, end = _exact_build_side(case, rng)
+    parts = KJ.build_partitions(key, val, begin, end)
+    p, c = parts.num_buckets, parts.capacity
+    k = KJ.plane_lanes(c)
+    assert parts.kv_planes.shape == parts.ts_planes.shape == (p, k)
+    assert parts.kv_planes.dtype == jnp.bfloat16
+    assert parts.nbytes == 4 * p * c * 4 + 2 * p * k * 2  # planes counted
+    assert parts.nbytes <= 8 * KJ.estimated_partition_bytes(key.shape[0])
+    if case == "wide":
+        assert c > 16 and k > 128
+    ts = 10
+    visible = (begin <= ts) & (ts < end) if build_ts else np.ones_like(key, bool)
+    n_s = 700  # not a whole number of row tiles
+    s_key = np.concatenate([
+        rng.permutation(key)[:300], EDGE_WORDS, np.zeros(20, np.int32),
+        np.ones(20, np.int32),
+        rng.integers(I32.min, I32.max, n_s, dtype=np.int64).astype(np.int32),
+    ])[:n_s]
+    s_key = rng.permutation(s_key)
+    s_val = rng.integers(I32.min, I32.max, n_s, dtype=np.int64).astype(np.int32)
+    words = jnp.asarray(np.stack([s_val, s_key], axis=1))
+    want = ref.hash_join_ref(jnp.asarray(s_key), jnp.asarray(s_val),
+                             jnp.asarray(key[visible]),
+                             jnp.asarray(val[visible]))
+    assert np.asarray(want[2]).any()
+    for got in (KJ.hash_join(words, parts, 1, 0, ts=ts, build_ts=build_ts,
+                             interpret=True),
+                KJ.hash_join_xla(words, parts, 1, 0, ts=ts,
+                                 build_ts=build_ts)):
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 # ------------------------------------------------------- MVCC snapshots
 def test_snapshot_join_byte_identical_to_frozen_copy(table, build_table):
     """A snapshot-pinned join under concurrent writes on BOTH sides equals
@@ -423,16 +507,22 @@ def test_probe_too_large_for_vmem_takes_counted_xla_route(
 
 def test_probe_vmem_guard_on_v5e():
     """On a v5e's budget the bench-scale build side (P = 4096) probes at the
-    full row tile, while a 1M-row build side (P = 65536) fits no tile."""
+    full row tile, pinned or not; a 1M-row build side (P = 65536) probes at
+    a quarter tile unpinned and fits no tile under a snapshot, whose second
+    plane array it cannot hold."""
     limit = common.vmem_limit_bytes("TPU v5 lite")
     eng = RelationalMemoryEngine()
 
     def parts(p):
         z = jnp.zeros((p, 19), jnp.int32)
-        return KJ.JoinPartitions(z, z, z, z)
+        planes = jnp.zeros((p, KJ.plane_lanes(19)), jnp.bfloat16)
+        return KJ.JoinPartitions(z, z, z, z, planes, planes)
 
-    assert eng._probe_block_rows(parts(4096), 18, limit) == eng.block_rows
-    assert eng._probe_block_rows(parts(65536), 18, limit) is None
+    for build_ts in (False, True):
+        assert eng._probe_block_rows(parts(4096), 18, limit,
+                                     build_ts) == eng.block_rows
+    assert eng._probe_block_rows(parts(65536), 18, limit) == eng.block_rows // 4
+    assert eng._probe_block_rows(parts(65536), 18, limit, True) is None
     with pytest.raises(ValueError, match="unknown TPU device kind"):
         common.vmem_limit_bytes("TPU v99")
 

@@ -156,24 +156,36 @@ def test_mixed_tick_in_one_call_exceeds_hbm(one_chip):
                     ROWS).compile()
 
 
+def _partitions(sharding, buckets, capacity):
+    """Shapes of a built ``JoinPartitions`` at ``(buckets, capacity)``."""
+    bucket = _shape(sharding, buckets, capacity)
+    planes = jax.ShapeDtypeStruct((buckets, KJ.plane_lanes(capacity)),
+                                  jnp.bfloat16, sharding=sharding)
+    return KJ.JoinPartitions(bucket, bucket, bucket, bucket, planes, planes)
+
+
+@pytest.mark.parametrize("build_ts", [False, True], ids=["unpinned", "pinned"])
 @pytest.mark.parametrize("probe_side", ["packed", "row_store"])
-def test_hash_join_probe_compiles(topo, one_chip, probe_side):
-    """The probe at the smoke's (P, C), with the row tile and scoped-VMEM
-    limit the engine's guard picks — so the footprint model is checked
-    against the compiler, too."""
+def test_hash_join_probe_compiles(topo, one_chip, probe_side, build_ts):
+    """The byte-plane probe at the smoke's (P, C), with the row tile and
+    scoped-VMEM limit the engine's guard picks — so the footprint model is
+    checked against the compiler, too."""
     limit = common.vmem_limit_bytes(topo.devices[0].device_kind)
-    bucket = _shape(one_chip, BUCKETS, CAPACITY)
-    parts = KJ.JoinPartitions(bucket, bucket, bucket, bucket)
+    parts = _partitions(one_chip, BUCKETS, CAPACITY)
+    assert parts.kv_planes.shape == (BUCKETS, 256)  # 8 planes x 19 slots
     width, key_word, ts_word = ((2, 1, -1) if probe_side == "packed"
                                 else (ROW_WORDS, 1, TS_WORD))
-    block_rows = RelationalMemoryEngine()._probe_block_rows(parts, width, limit)
+    block_rows = RelationalMemoryEngine()._probe_block_rows(
+        parts, width, limit, build_ts)
     assert block_rows == BLOCK_ROWS
     rows = _call_rows((width, 1, 1, 1))
     _assert_fits_call_budget(_assert_kernel(KJ._hash_join.lower(
-        _shape(one_chip, rows, width), *parts, _shape(one_chip, 1, 1),
-        key_word=key_word, val_word=0, ts_word=ts_word, build_ts=True,
-        block_rows=block_rows, interpret=False,
-        vmem_limit=KJ.probe_vmem_footprint_bytes(parts, width, block_rows))))
+        _shape(one_chip, rows, width), parts.kv_planes,
+        parts.ts_planes if build_ts else None, _shape(one_chip, 1, 1),
+        key_word=key_word, val_word=0, ts_word=ts_word, build_ts=build_ts,
+        capacity=CAPACITY, block_rows=block_rows, interpret=False,
+        vmem_limit=KJ.probe_vmem_footprint_bytes(parts, width, block_rows,
+                                                 build_ts))))
 
 
 def test_project_mlp_compiles(one_chip):

@@ -159,6 +159,8 @@ def test_span_nesting_and_shared_ticket_args(monkeypatch, tables):
         0, 1, 2, 3]
     for s in rec.named("engine.hash_join"):
         assert s.parent.name == "engine.finish_join"
+        # the byte-plane contraction's width: whole 128-lane tiles
+        assert s.args["lanes"] > 0 and s.args["lanes"] % 128 == 0
     for s in rec.named("planner.compile_plan"):
         assert s.parent is tick
         assert s.args["route"]
