@@ -6,6 +6,12 @@ by name: the cell in ``BENCHMARK.json``, the configuration in
 the kind of client the mix names in ``bench/clients/<clients>.py``, each
 metric's reader in ``bench/metrics/<metric>.py``.
 
+A cell's ``chips`` picks the engine: on one chip the single-device
+``RelationalMemoryEngine``; on more, a ``ShardedEngine`` that holds the
+table as that many row ranges, one on each chip.  The result's
+``memory_peak_bytes`` is the fullest chip's peak, with every chip's in
+``memory_peak_bytes_per_chip``.
+
 Every read goes the served path: ``QueryServer.submit`` on a server running
 its own ``start()`` loop -> ``compile_plan`` -> ``execute_many`` -> the
 Pallas kernels.  A read is timed from just before its submit until the
@@ -30,7 +36,7 @@ import sys
 import tempfile
 import time
 
-from bench import check, reduce, traffic
+from bench import check, reduce, spans, traffic
 from bench.peaks import peaks
 from bench.reference import Reference, make_build_columns, make_columns
 
@@ -97,8 +103,9 @@ class Traced:
     device: reduce.DeviceTime
     ticks: int
     need_bytes: int
-    hbm_bytes_per_s: float
+    hbm_bytes_per_s: float  # of every chip that holds a part of the table
     breakdown: dict
+    spans: dict  # bench.spans.metrics: the idle split on the root chip
 
 
 @dataclasses.dataclass
@@ -122,13 +129,16 @@ class Clock:
         self.programs = 0
         self.cache_hits = 0
         self.seconds = 0.0
+        self.names: list[str] = []  # of the programs obtained, in order
         jax.monitoring.register_event_duration_secs_listener(self._on_duration)
         jax.monitoring.register_event_listener(self._on_event)
 
-    def _on_duration(self, event: str, duration: float, **_) -> None:
+    def _on_duration(self, event: str, duration: float, fun_name="?",
+                     **_) -> None:
         if event == "/jax/core/compile/backend_compile_duration":
             self.programs += 1
             self.seconds += duration
+            self.names.append(fun_name)
 
     def _on_event(self, event: str, **_) -> None:
         if event == "/jax/compilation_cache/cache_hits":
@@ -190,8 +200,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     configure_compile_cache()
     devices = jax.devices()
     dev = devices[0]
-    if require_accelerator and (dev.platform != "tpu"
-                                or len(devices) < cell.chips):
+    used = devices[:cell.chips]
+    if len(used) < cell.chips or (require_accelerator
+                                  and dev.platform != "tpu"):
         raise NoAccelerator(
             f"cell {workload} needs {cell.chips} TPU chip(s); JAX found "
             f"{len(devices)} {dev.platform} device(s)")
@@ -200,7 +211,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         raise NoAccelerator("a traced run reads device time: it needs a TPU")
     clock = Clock()
 
-    from repro.core import RelationalMemoryEngine, RelationalTable, benchmark_schema
+    from repro.core import RelationalTable, benchmark_schema
     from repro.serve import QueryServer
 
     cfg = cell.config
@@ -223,10 +234,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
                          cfg["column_bytes"]), bcols)
     split["table_build"] = time.perf_counter() - t
 
-    engine = RelationalMemoryEngine(**cfg["engine"])
+    engine = make_engine(cfg, used)
     server = QueryServer(engine, **cfg["server"])
     t = time.perf_counter()
-    jax.block_until_ready(engine.device_chunks(table))
+    jax.block_until_ready(resident(engine, table))
     split["upload"] = time.perf_counter() - t
 
     probe_word = {c.name: schema.word_offset(c.name) for c in schema.columns}
@@ -253,7 +264,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
         print("setup_s split: " + json.dumps(split), file=sys.stderr,
               flush=True)
 
-        result = _window(cell, client, engine, dev, seed, seconds, trace,
+        result = _window(cell, client, engine, used, seed, seconds, trace,
                          n, bcfg["rows"], peak, setup_s)
     finally:
         client.stop()
@@ -261,12 +272,15 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     programs = clock.counts()
     print(f"programs obtained, compiled: {setup_programs} in set-up, "
           f"{(programs[0] - setup_programs[0], programs[1] - setup_programs[1])}"
-          " in the window", file=sys.stderr, flush=True)
+          f" in the window: {clock.names[setup_programs[0]:]}",
+          file=sys.stderr, flush=True)
 
-    mem = dev.memory_stats() or {}
+    peaks_per_chip = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                      for d in used]
     result["device"] = {"platform": dev.platform, "kind": dev.device_kind,
                         "count": len(devices),
-                        "memory_peak_bytes": mem.get("peak_bytes_in_use", 0),
+                        "memory_peak_bytes": max(peaks_per_chip),
+                        "memory_peak_bytes_per_chip": peaks_per_chip,
                         **result["device"]}
     samples = result.pop("samples")
     del client, server, engine, table, build
@@ -283,10 +297,43 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     return {k: result[k] for k in order if k in result}
 
 
-def _memory(dev) -> str:
-    mem = dev.memory_stats() or {}
-    return ", ".join(f"{k} {mem[k]}" for k in (
-        "bytes_in_use", "largest_free_block_bytes") if k in mem)
+def make_engine(cfg: dict, used: list):
+    """The engine over the cell's chips ``used``: the single-device engine
+    on one, else a ``ShardedEngine`` with one shard on each."""
+    from repro.core import RelationalMemoryEngine
+    from repro.core.distributed import ShardedEngine
+
+    chips = len(used)
+    if chips == 1:
+        return RelationalMemoryEngine(**cfg["engine"])
+    import numpy as np
+    from jax.sharding import Mesh
+
+    return ShardedEngine(mesh=Mesh(np.array(used), ("data",)),
+                         num_shards=chips, **cfg["engine"])
+
+
+def resident(engine, table) -> list:
+    """The table's chunks as the engine keeps them on the device: every
+    shard's own on its own chip for a sharded engine, nothing gathered."""
+    from repro.core.distributed import ShardedEngine
+
+    if not isinstance(engine, ShardedEngine):
+        return list(engine.device_chunks(table))
+    parts = engine.rowstore.shard_parts(table)
+    print("upload: shard rows on devices " + json.dumps(
+        [[c.rows, sorted(str(d) for d in c.words.devices())]
+         for chunks in parts for c in chunks]), file=sys.stderr, flush=True)
+    return [c.words for chunks in parts for c in chunks]
+
+
+def _memory(devs) -> str:
+    out = []
+    for i, dev in enumerate(devs):
+        mem = dev.memory_stats() or {}
+        out.append(f"chip {i}: " + ", ".join(f"{k} {mem[k]}" for k in (
+            "bytes_in_use", "largest_free_block_bytes") if k in mem))
+    return "; ".join(out)
 
 
 class _GcClock:
@@ -309,7 +356,7 @@ class _GcClock:
         gc.callbacks.remove(self._on_gc)
 
 
-def _window(cell: Cell, client, engine, dev, seed: int, seconds: float,
+def _window(cell: Cell, client, engine, devs, seed: int, seconds: float,
             trace: bool, probe_rows: int, build_rows: int, peak, setup_s):
     import jax
 
@@ -336,7 +383,7 @@ def _window(cell: Cell, client, engine, dev, seed: int, seconds: float,
         records.extend(recs)
         print(f"step {len(steps)}: at {t - start} s, "
               f"{time.perf_counter() - t} s, {len(recs)} reads, kept "
-              f"{sorted(answers)}; {_memory(dev)}", file=sys.stderr, flush=True)
+              f"{sorted(answers)}; {_memory(devs)}", file=sys.stderr, flush=True)
         steps.append(t)
         return recs
 
@@ -391,8 +438,8 @@ def _window(cell: Cell, client, engine, dev, seed: int, seconds: float,
     ticks = reduce.group_ticks(answered)
     print(f"window: {len(answered)} reads answered, sent in {seconds} s and "
           f"answered in {span} s, {len(ticks)} ticks, routes "
-          f"{sorted({r.route for r in answered if r.route})}",
-          file=sys.stderr, flush=True)
+          f"{sorted({r.route for r in answered if r.route})}, engine "
+          f"counters {window.engine_delta}", file=sys.stderr, flush=True)
     out = {"attempted": len(records),
            "failed": sum(r.failed for r in records),
            "metrics": values, "device": device,
@@ -412,11 +459,22 @@ def _reduce_trace(trace_dir, recs, chips, probe_rows, build_rows, peak) -> Trace
                           recursive=True)
         if len(paths) != 1:
             raise RuntimeError(f"want one trace file, found {paths}")
-        tr = reduce.load_trace(jax.profiler.ProfileData.from_file(paths[0]))
+        xspace = jax.profiler.ProfileData.from_file(paths[0])
+        return reduce_traced(xspace, recs, chips, probe_rows, build_rows,
+                             peak)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
-    span = tr.annotation("bench.traced")
-    lo, hi = span.start_ns, span.end_ns
+
+
+def reduce_traced(xspace, recs, chips, probe_rows, build_rows, peak) -> Traced:
+    """What the readers read of one traced window.  Device time is the mean
+    over the ``chips`` device planes; the table's need is met by all of
+    them, so its bandwidth is ``chips`` times one chip's.  The program
+    spans' idle split and the idle gaps are the root chip's
+    (``reduce.ROOT_PLANE``)."""
+    tr = reduce.load_trace(xspace)
+    window = tr.annotation("bench.traced")
+    lo, hi = window.start_ns, window.end_ns
     dt = reduce.device_time(tr, lo, hi, chips)
     done = [r for r in recs if r.t_ready is not None]
     ticks = reduce.group_ticks(done)
@@ -429,4 +487,8 @@ def _reduce_trace(trace_dir, recs, chips, probe_rows, build_rows, peak) -> Trace
         "device_ops": reduce.top_ops(tr, lo, hi),
         "idle_gaps": reduce.idle_gaps(tr, lo, hi, ignore=("bench.traced",)),
     }
-    return Traced(dt, len(ticks), need, peak["hbm_bytes_per_s"], breakdown)
+    span_metrics = spans.metrics(spans.serving_thread(xspace).spans.spans,
+                                 spans.busy_intervals(tr, lo, hi), lo, hi,
+                                 len(ticks))
+    return Traced(dt, len(ticks), need, peak["hbm_bytes_per_s"] * chips,
+                  breakdown, span_metrics)

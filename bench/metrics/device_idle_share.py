@@ -1,5 +1,6 @@
 """Share of the traced window in which no operation ran on the device
-(one minus the union of device operation intervals over the window)."""
+(one minus the union of device operation intervals over the window); on
+several chips, the mean of each chip's busy time over the window."""
 
 
 def read(w):

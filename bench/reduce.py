@@ -121,6 +121,7 @@ class Trace:
 
 
 DEVICE_PLANE_PREFIX = "/device:TPU:"
+ROOT_PLANE = DEVICE_PLANE_PREFIX + "0"  # the harness's devices[0]
 DEVICE_OPS_LINE = "XLA Ops"
 HOST_PLANE = "/host:CPU"
 
@@ -140,6 +141,19 @@ def load_trace(xspace) -> Trace:
                         for line in plane.lines for e in line.events
                         if e.duration_ns > 0)
     return Trace(device_ops, host)
+
+
+def root_ops(trace: Trace) -> list[Event]:
+    """The operations of the root chip, where a sharded engine gathers its
+    row outputs: the plane named :data:`ROOT_PLANE`, in whatever order the
+    trace lists its planes.  No operations where the trace has no device
+    plane."""
+    if not trace.device_ops:
+        return []
+    if ROOT_PLANE not in trace.device_ops:
+        raise ValueError(f"no {ROOT_PLANE!r} among the trace's device "
+                         f"planes {sorted(trace.device_ops)}")
+    return trace.device_ops[ROOT_PLANE]
 
 
 def _clip(events, lo: float, hi: float) -> list[tuple[float, float]]:
@@ -217,15 +231,14 @@ def top_ops(trace: Trace, lo: float, hi: float, n: int = 10) -> list:
 
 def idle_gaps(trace: Trace, lo: float, hi: float, n: int = 10,
               ignore: tuple[str, ...] = ()) -> list:
-    """The longest idle gaps of the first device in the window, each named
+    """The longest idle gaps of the root chip in the window, each named
     by what the host was doing: the shortest host event that spans the
     gap's middle, else the one that overlaps it most (``ignore`` names
     spans to pass over, such as the benchmark's own around the window)."""
     if not trace.device_ops:
         return []
-    ops = next(iter(trace.device_ops.values()))
     gaps, cur = [], lo
-    for s, t in sorted(_clip(ops, lo, hi)):
+    for s, t in sorted(_clip(root_ops(trace), lo, hi)):
         if s > cur:
             gaps.append((cur, s))
         cur = max(cur, t)

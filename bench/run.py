@@ -3,12 +3,16 @@
     python3 bench/run.py --workload rm64.analytic --seed 7 --seconds 30 --trace 0
 
 Run from the root of a checkout.  The cell's configuration, traffic mix and
-metrics are read from ``BENCHMARK.json`` and ``bench/``.  The last line of
+metrics are read from ``BENCHMARK.json`` and ``bench/``.  A configuration
+with ``"shards": n`` above 1 is served by the sharded engine, its table in
+n row ranges, one on each of the cell's n chips.  The last line of
 standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics with ``--trace 0``, its per-layer
-metrics with ``--trace 1``), ``device``, with ``--trace 1`` a
-``breakdown``, and ``checks``: each number the reference comparison used,
-beside its limit (also the last lines of standard error).
+metrics with ``--trace 1``), ``device`` (with the fullest chip's
+``memory_peak_bytes`` and every chip's in ``memory_peak_bytes_per_chip``),
+with ``--trace 1`` a ``breakdown``, and ``checks``: each number the
+reference comparison used, beside its limit (also the last lines of
+standard error).
 
 Exit codes: 0 after a result line; 2 when the engine cannot be imported
 (no ``src/`` beside ``bench/``); 3 when JAX finds no TPU or fewer chips than

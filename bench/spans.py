@@ -5,8 +5,8 @@ The server, planner and engine mark each layer boundary with a span
 ``engine.execute_many`` and the engine's per-range children; the tree is in
 ``docs/metrics.md``).  The spans are ``TraceAnnotation``s, so a profiler
 trace holds them on the host thread that served the ticks, on the same clock
-as the device's operations.  This module puts each instant of the first
-device's idle time inside the benchmark's ``bench.traced`` span down to one
+as the device's operations.  This module puts each instant of the root
+chip's idle time inside the benchmark's ``bench.traced`` span down to one
 layer, by the spans open on the serving thread at that instant:
 
 * ``idle_dispatch_ms_per_tick`` — an ``engine.*`` span is open: the engine
@@ -23,10 +23,10 @@ to ``device_idle_share`` / 100 x window / ticks.  ``plan_ms_per_read`` is
 the summed ``planner.compile_plan`` time over the compiles that began in the
 window.
 
-The harness's metric readers see no spans (``bench/harness.py`` keeps only
-the reduced device time), so these numbers come from this module's script,
-which runs one traced window of a cell through the harness and reduces the
-same trace the harness reduces::
+The harness hands :func:`metrics` of its traced window to the readers
+(``bench/metrics/<name>.py``, ``Traced.spans``), so a ``--trace 1`` run
+prints the four numbers.  This module's script runs one traced window of a
+cell through the harness and reduces the same trace further::
 
     python3 bench/spans.py --workload rm64.analytic --seed 7 --seconds 51
 
@@ -178,11 +178,8 @@ def overlap(a, b) -> float:
 
 
 def busy_intervals(trace: reduce.Trace, lo: float, hi: float) -> list:
-    """The first device's operations inside ``[lo, hi]``, merged."""
-    if not trace.device_ops:
-        return []
-    ops = next(iter(trace.device_ops.values()))
-    return merge(reduce._clip(ops, lo, hi))
+    """The root chip's operations inside ``[lo, hi]``, merged."""
+    return merge(reduce._clip(reduce.root_ops(trace), lo, hi))
 
 
 def idle_intervals(busy, lo: float, hi: float) -> list:

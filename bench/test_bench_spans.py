@@ -8,7 +8,7 @@ import types
 
 import pytest
 
-from bench import reduce, spans
+from bench import harness, reduce, spans
 
 MS = 1e6  # nanoseconds
 
@@ -24,17 +24,24 @@ def _plane(name, lines):
         types.SimpleNamespace(name=n, events=evs) for n, evs in lines])
 
 
-def _xspace():
-    """A window of 1000 ms.  The device is busy 100-200, 300-400 and
-    600-900 ms, so idle 500 ms: 100 of it inside an engine span (200-300),
-    270 inside server or planner spans with no engine span open (50-100,
-    400-450, 480-600, 900-950), and 130 with no program span open (0-50,
-    450-480, 950-1000)."""
-    device = _plane("/device:TPU:0", [(reduce.DEVICE_OPS_LINE, [
+def _xspace(chips: int = 1, root_last: bool = False):
+    """A window of 1000 ms.  Each of ``chips`` devices is busy 100-200,
+    300-400 and 600-900 ms, so idle 500 ms: 100 of it inside an engine span
+    (200-300), 270 inside server or planner spans with no engine span open
+    (50-100, 400-450, 480-600, 900-950), and 130 with no program span open
+    (0-50, 450-480, 950-1000).  With ``root_last`` the device planes are
+    listed from the last chip down to chip 0, and every chip but chip 0 is
+    also busy 950-1000 ms."""
+    devices = [_plane(f"/device:TPU:{i}", [(reduce.DEVICE_OPS_LINE, [
         _event("%fusion.1 = s32[8] fusion(s32[8] %a)", 100, 200),
         _event("%copy.2 = s32[8] copy(s32[8] %b)", 300, 400),
         _event('%k.3 = s32[8] custom-call(s32[8] %c), '
-               'custom_call_target="tpu_custom_call"', 600, 900)])])
+               'custom_call_target="tpu_custom_call"', 600, 900),
+        *([_event("%copy.4 = s32[8] copy(s32[8] %d)", 950, 1000)]
+          if root_last and i else [])])])
+        for i in range(chips)]
+    if root_last:
+        devices.reverse()
     serving = [
         _event("server.tick", 50, 450, tick=1),
         _event("planner.compile_plan", 60, 90, tick=1, ticket=1,
@@ -56,7 +63,7 @@ def _xspace():
               _event("ExecutePrepare", 410, 590)]
     host = _plane(reduce.HOST_PLANE, [("python3", client),
                                       ("python3", serving)])
-    return types.SimpleNamespace(planes=[device, host])
+    return types.SimpleNamespace(planes=[*devices, host])
 
 
 def test_idle_split_partitions_idle_time():
@@ -219,3 +226,98 @@ def test_recorded_chip_trace_with_spans():
     # a fused-pass call: 31 eager programs and 16 transfers a row range
     assert got["dispatches"]["by_span"]["engine.scan_multi"] == [
         3 * 8 * 31, 3 * 8 * 16]
+
+
+PROBE_ROWS, BUILD_ROWS = 1 << 20, 1 << 16
+V5E = {"hbm_bytes_per_s": 819e9}
+
+
+def _traced_window(chips: int = 1, ticks: int = 2) -> harness.Window:
+    """The synthetic trace as the harness hands it to the readers: ``ticks``
+    ticks of one kernel-served read each, reading probe words 0 and 1 and
+    build word 1, 100 result bytes."""
+    recs = [harness.ReadRecord(None, 0.0, t_ready=1.0, admitted_at=float(i),
+                               route="rme", result_bytes=100,
+                               probe_words=frozenset({0, 1}),
+                               build_words=frozenset({1}))
+            for i in range(ticks)]
+    traced = harness.reduce_traced(_xspace(chips), recs, chips, PROBE_ROWS,
+                                   BUILD_ROWS, V5E)
+    return harness.Window(1.0, 1.0, recs, {}, traced)
+
+
+def _read(name, w):
+    return harness._read_metric(name, w)
+
+
+def test_span_metrics_reach_the_readers():
+    """The harness reduces the spans before the trace goes, and each of the
+    four per-layer readers finds its number; the three idle metrics sum to
+    the idle share read from the same window."""
+    w = _traced_window(ticks=2)
+    assert _read("idle_dispatch_ms_per_tick", w) == pytest.approx(100 / 2)
+    assert _read("idle_frontend_ms_per_tick", w) == pytest.approx(270 / 2)
+    assert _read("idle_between_ticks_ms_per_tick", w) == pytest.approx(
+        130 / 2)
+    assert _read("plan_ms_per_read", w) == pytest.approx((30 + 40) / 2)
+    idle = sum(_read(f"idle_{k}_ms_per_tick", w)
+               for k in ("dispatch", "frontend", "between_ticks"))
+    share = _read("device_idle_share", w)
+    assert share == pytest.approx(50.0)
+    assert idle == pytest.approx(
+        share / 100 * w.traced.device.window_ns * 1e-6 / 2, rel=1e-12)
+
+
+def test_span_metrics_absent_without_a_trace():
+    w = harness.Window(1.0, 1.0, [], {})
+    for name in ("idle_dispatch_ms_per_tick", "idle_frontend_ms_per_tick",
+                 "idle_between_ticks_ms_per_tick", "plan_ms_per_read"):
+        assert _read(name, w) is None
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_hbm_roofline_over_the_chips(chips):
+    """Each of ``chips`` device planes is busy 500 ms of the 1000 ms window;
+    the need of two ticks is met at ``chips`` x 819 GB/s over that busy
+    time, the mean over the chips."""
+    w = _traced_window(chips)
+    need = 2 * (PROBE_ROWS * 4 * 2 + BUILD_ROWS * 4 * 1 + 100)
+    assert w.traced.need_bytes == need
+    assert w.traced.device.busy_ns == pytest.approx(500 * MS)
+    assert w.traced.hbm_bytes_per_s == chips * 819e9
+    want = 100.0 * need / (chips * 819e9) / 0.5
+    assert _read("hbm_roofline", w) == pytest.approx(want, rel=1e-12)
+    assert _read("device_idle_share", w) == pytest.approx(50.0)
+    # the idle split and the idle gaps stay the root chip's
+    assert _read("idle_dispatch_ms_per_tick", w) == pytest.approx(100 / 2)
+    assert len(w.traced.breakdown["idle_gaps"]) == 4
+
+
+@pytest.mark.parametrize("chips, root_last", [(1, False), (4, False),
+                                              (4, True)])
+def test_root_chip_is_read_by_name(chips, root_last):
+    """The busy intervals, the idle gaps and the idle split are chip 0's,
+    whichever plane the trace lists first: with ``root_last`` the first
+    plane is chip 3's, which is busy 950-1000 ms where chip 0 is idle."""
+    xs = _xspace(chips, root_last)
+    tr = reduce.load_trace(xs)
+    assert (next(iter(tr.device_ops)) == reduce.ROOT_PLANE) != root_last
+    lo, hi = 0.0, 1000 * MS
+    assert spans.busy_intervals(tr, lo, hi) == pytest.approx(
+        [(100 * MS, 200 * MS), (300 * MS, 400 * MS), (600 * MS, 900 * MS)])
+    gaps = reduce.idle_gaps(tr, lo, hi, ignore=("bench.traced",))
+    assert [round(g, 6) for _, g in gaps] == [0.2, 0.1, 0.1, 0.1]
+    m = spans.reduce_spans(xs, ticks=2)["metrics"]
+    assert m["idle_dispatch_ms_per_tick"] == pytest.approx(100 / 2)
+    assert m["idle_frontend_ms_per_tick"] == pytest.approx(270 / 2)
+    assert m["idle_between_ticks_ms_per_tick"] == pytest.approx(130 / 2)
+
+
+def test_trace_without_the_root_plane_is_refused():
+    xs = _xspace(2)
+    xs.planes = xs.planes[1:]  # chip 1's plane and the host's
+    tr = reduce.load_trace(xs)
+    with pytest.raises(ValueError, match="TPU:0"):
+        reduce.idle_gaps(tr, 0.0, 1000 * MS)
+    with pytest.raises(ValueError, match="TPU:0"):
+        spans.busy_intervals(tr, 0.0, 1000 * MS)
