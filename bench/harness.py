@@ -105,7 +105,9 @@ class Traced:
     need_bytes: int
     hbm_bytes_per_s: float  # of every chip that holds a part of the table
     breakdown: dict
-    spans: dict  # bench.spans.metrics: the idle split on the root chip
+    # bench.spans.metrics: the idle split on the root chip, and every
+    # serving-thread span's ``span_ms_per_tick`` and ``span_count_per_tick``
+    spans: dict
 
 
 @dataclasses.dataclass
@@ -115,7 +117,7 @@ class Window:
     setup_s: float
     seconds: float  # from the window's opening to its last answer
     reads: list[ReadRecord]  # every read sent in the window and answered
-    engine_delta: dict[str, int]
+    engine_delta: dict[str, int]  # of every counter in engine_counters()
     traced: Traced | None = None
 
 
@@ -178,8 +180,16 @@ def load_client(kind: str):
     return importlib.import_module(f"bench.clients.{kind}").Client
 
 
-def _engine_counters(engine) -> dict[str, int]:
-    return {"kernel_fallbacks": engine.stats.kernel_fallbacks}
+# EngineStats fields that hold a last value, not a count: no window delta
+ENGINE_GAUGES = frozenset({"last_block_rows"})
+
+
+def engine_counters(engine) -> dict[str, int]:
+    """Every counter of the engine's ``EngineStats``, by field name, so a
+    reader of a counter needs no edit here."""
+    return {f.name: getattr(engine.stats, f.name)
+            for f in dataclasses.fields(engine.stats)
+            if f.name not in ENGINE_GAUGES}
 
 
 def _read_metric(name: str, window: Window):
@@ -365,7 +375,7 @@ def _window(cell: Cell, client, engine, devs, seed: int, seconds: float,
     kept: dict[str, tuple] = {}
     records: list[ReadRecord] = []
     traced_recs: list[ReadRecord] = []
-    before = _engine_counters(engine)
+    before = engine_counters(engine)
     trace_dir = None
     gc_clock = _GcClock()
     client.start(seed)
@@ -403,7 +413,7 @@ def _window(cell: Cell, client, engine, devs, seed: int, seconds: float,
             continue
         step()
     gc_clock.remove()
-    after = _engine_counters(engine)
+    after = engine_counters(engine)
 
     answered = [r for r in records if r.t_ready is not None]
     if trace and trace_dir is None:
@@ -416,8 +426,9 @@ def _window(cell: Cell, client, engine, devs, seed: int, seconds: float,
     if trace:
         window.traced = _reduce_trace(trace_dir, traced_recs, cell.chips,
                                       probe_rows, build_rows, peak)
-        device = {"busy_s": window.traced.device.busy_ns * 1e-9,
-                  "window_s": window.traced.device.window_ns * 1e-9}
+        dt = window.traced.device
+        device = {"busy_s": dt.busy_ns * 1e-9, "window_s": dt.window_ns * 1e-9,
+                  "busy_s_per_chip": [ns * 1e-9 for ns in dt.busy_ns_per_chip]}
         breakdown = window.traced.breakdown
     metrics = cell.per_layer if trace else cell.end_to_end
     values = {}
@@ -439,7 +450,8 @@ def _window(cell: Cell, client, engine, devs, seed: int, seconds: float,
     print(f"window: {len(answered)} reads answered, sent in {seconds} s and "
           f"answered in {span} s, {len(ticks)} ticks, routes "
           f"{sorted({r.route for r in answered if r.route})}, engine "
-          f"counters {window.engine_delta}", file=sys.stderr, flush=True)
+          f"counters {({k: v for k, v in window.engine_delta.items() if v})}",
+          file=sys.stderr, flush=True)
     out = {"attempted": len(records),
            "failed": sum(r.failed for r in records),
            "metrics": values, "device": device,

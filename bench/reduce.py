@@ -194,26 +194,48 @@ def op_name(name: str) -> str:
 
 @dataclasses.dataclass
 class DeviceTime:
-    """Device time inside one window, averaged over the chips used."""
+    """Device time inside one window: ``busy_ns``, ``kernel_ns`` and
+    ``glue_ns`` averaged over the chips used, and each chip's busy and
+    kernel time apart, in device order: :data:`ROOT_PLANE` first, then
+    ``/device:TPU:1`` and on (:func:`chip_planes`)."""
 
     window_ns: float
     busy_ns: float
     kernel_ns: float
     glue_ns: float
+    busy_ns_per_chip: list[float] = dataclasses.field(default_factory=list)
+    kernel_ns_per_chip: list[float] = dataclasses.field(default_factory=list)
+
+
+def chip_planes(trace: Trace, chips: int) -> list[str]:
+    """The device planes of a cell's ``chips`` chips, ``/device:TPU:0`` to
+    ``/device:TPU:<chips - 1>``, the harness's ``devices[:chips]``.  The
+    planes of other chips on the host are not the cell's and are left
+    out; a trace that lacks one of the cell's planes is refused."""
+    want = [f"{DEVICE_PLANE_PREFIX}{i}" for i in range(chips)]
+    missing = [p for p in want if p not in trace.device_ops]
+    if missing:
+        raise ValueError(f"no {missing} among the trace's device planes "
+                         f"{sorted(trace.device_ops)} for a cell of "
+                         f"{chips} chips")
+    return want
 
 
 def device_time(trace: Trace, lo: float, hi: float, chips: int) -> DeviceTime:
-    busy = kernel = glue = 0.0
-    for ops in trace.device_ops.values():
-        spans = _clip(ops, lo, hi)
-        busy += union_ns(spans)
+    busy, kernel, glue = [], [], 0.0
+    for plane in chip_planes(trace, chips):
+        ops = trace.device_ops[plane]
+        busy.append(union_ns(_clip(ops, lo, hi)))
+        chip_kernel = 0.0
         for e in ops:
             d = max(0.0, min(e.end_ns, hi) - max(e.start_ns, lo))
             if is_kernel(e.name):
-                kernel += d
+                chip_kernel += d
             else:
                 glue += d
-    return DeviceTime(hi - lo, busy / chips, kernel / chips, glue / chips)
+        kernel.append(chip_kernel)
+    return DeviceTime(hi - lo, sum(busy) / chips, sum(kernel) / chips,
+                      glue / chips, busy, kernel)
 
 
 def top_ops(trace: Trace, lo: float, hi: float, n: int = 10) -> list:

@@ -3,14 +3,16 @@
     python3 bench/run.py --workload rm64.analytic --seed 7 --seconds 30 --trace 0
 
 Run from the root of a checkout.  The cell's configuration, traffic mix and
-metrics are read from ``BENCHMARK.json`` and ``bench/``.  A configuration
-with ``"shards": n`` above 1 is served by the sharded engine, its table in
-n row ranges, one on each of the cell's n chips.  The last line of
+metrics are read from ``BENCHMARK.json`` and ``bench/``.  A cell whose
+``chips`` n is above 1 is served by the sharded engine, its table in n row
+ranges, one on each of its n chips.  The last line of
 standard output is one JSON object: ``correct``, ``attempted``, ``failed``,
 ``metrics`` (the cell's end-to-end metrics with ``--trace 0``, its per-layer
 metrics with ``--trace 1``), ``device`` (with the fullest chip's
-``memory_peak_bytes`` and every chip's in ``memory_peak_bytes_per_chip``),
-with ``--trace 1`` a ``breakdown``, and ``checks``: each number the
+``memory_peak_bytes`` and every chip's in ``memory_peak_bytes_per_chip``;
+with ``--trace 1`` also ``busy_s``, the mean over the chips, ``window_s``
+and each chip's in ``busy_s_per_chip``, chip 0 first), with ``--trace 1``
+a ``breakdown``, and ``checks``: each number the
 reference comparison used, beside its limit (also the last lines of
 standard error).
 

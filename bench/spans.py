@@ -21,7 +21,10 @@ layer, by the spans open on the serving thread at that instant:
 each over the traced ticks.  The three partition the idle time, so they sum
 to ``device_idle_share`` / 100 x window / ticks.  ``plan_ms_per_read`` is
 the summed ``planner.compile_plan`` time over the compiles that began in the
-window.
+window.  Beside them, ``span_ms_per_tick`` and ``span_count_per_tick`` map
+every span name on the serving thread to its summed time (inclusive of the
+spans nested in it) and its count over the spans that began in the window,
+per tick, so a reader of a new span needs no edit here.
 
 The harness hands :func:`metrics` of its traced window to the readers
 (``bench/metrics/<name>.py``, ``Traced.spans``), so a ``--trace 1`` run
@@ -51,7 +54,7 @@ import dataclasses  # noqa: E402
 import json  # noqa: E402
 import pathlib  # noqa: E402
 import sys  # noqa: E402
-from collections import Counter  # noqa: E402
+from collections import Counter, defaultdict  # noqa: E402
 
 ROOT = pathlib.Path(__file__).resolve().parent.parent
 if __name__ == "__main__":
@@ -207,29 +210,42 @@ def idle_split(spans: list[Span], busy, lo: float, hi: float) -> dict:
             "between_ticks_ns": total - in_program, "idle_ns": total}
 
 
-def plan_ms_per_read(spans: list[Span], lo: float, hi: float) -> float | None:
-    compiles = [s for s in spans
-                if s.name == COMPILE_SPAN and lo <= s.start_ns <= hi]
-    if not compiles:
-        return None
-    return sum(s.dur_ns for s in compiles) * 1e-6 / len(compiles)
+def span_totals(spans: list[Span], lo: float,
+                hi: float) -> dict[str, tuple[float, int]]:
+    """Each span name's summed duration in nanoseconds and its count, over
+    the spans that begin inside ``[lo, hi]``.  A span's duration is
+    inclusive: the time of the spans nested in it is counted in it too."""
+    ns: dict[str, float] = defaultdict(float)
+    count: Counter = Counter()
+    for s in spans:
+        if lo <= s.start_ns <= hi:
+            ns[s.name] += s.dur_ns
+            count[s.name] += 1
+    return {name: (ns[name], count[name]) for name in ns}
 
 
 def metrics(spans: list[Span], busy, lo: float, hi: float,
             ticks: int) -> dict:
-    """The four per-layer numbers of one traced window."""
+    """The four per-layer numbers of one traced window, and for each span
+    name on the serving thread its time and its count per tick
+    (``span_ms_per_tick``, ``span_count_per_tick``; :func:`span_totals`)."""
     if not ticks:
         return {}
     split = idle_split(spans, busy, lo, hi)
+    totals = span_totals(spans, lo, hi)
     out = {
         "idle_dispatch_ms_per_tick": split["dispatch_ns"] * 1e-6 / ticks,
         "idle_frontend_ms_per_tick": split["frontend_ns"] * 1e-6 / ticks,
         "idle_between_ticks_ms_per_tick":
             split["between_ticks_ns"] * 1e-6 / ticks,
+        "span_ms_per_tick": {name: ns * 1e-6 / ticks
+                             for name, (ns, _) in totals.items()},
+        "span_count_per_tick": {name: n / ticks
+                                for name, (_, n) in totals.items()},
     }
-    plan = plan_ms_per_read(spans, lo, hi)
-    if plan is not None:
-        out["plan_ms_per_read"] = plan
+    if COMPILE_SPAN in totals:
+        ns, n = totals[COMPILE_SPAN]
+        out["plan_ms_per_read"] = ns * 1e-6 / n
     return out
 
 

@@ -79,6 +79,7 @@ def test_device_time_kernel_and_glue():
     dt = reduce.device_time(tr, 0, 120, chips=1)
     assert dt.busy_ns == 70 and dt.window_ns == 120
     assert dt.kernel_ns == 40 and dt.glue_ns == 35
+    assert dt.busy_ns_per_chip == [70] and dt.kernel_ns_per_chip == [40]
     clipped = reduce.device_time(tr, 20, 70, chips=1)
     assert clipped.busy_ns == 20 + 10 and clipped.kernel_ns == 20
 
@@ -115,6 +116,7 @@ def test_recorded_chip_trace_reduces():
     # what the run reported as busy_s and window_s
     assert dt.window_ns * 1e-9 == pytest.approx(4.973447503, rel=1e-12)
     assert dt.busy_ns * 1e-9 == pytest.approx(4.7458896180000005, rel=1e-12)
+    assert dt.busy_ns_per_chip == [dt.busy_ns]
     # the kernels: 32 fused scans and 32 join probes over two rounds of
     # 16 row ranges; everything else is glue
     ops = tr.device_ops["/device:TPU:0"]
@@ -123,6 +125,7 @@ def test_recorded_chip_trace_reduces():
     assert len(kernels) == 64
     assert dt.kernel_ns + dt.glue_ns >= dt.busy_ns
     assert dt.kernel_ns * 1e-9 == pytest.approx(4.489652295, rel=1e-9)
+    assert dt.kernel_ns_per_chip == [dt.kernel_ns]
     top = reduce.top_ops(tr, lo, hi)
     assert [name for name, _ in top[:3]] == ["_hash_join", "copy", "_scan_multi"]
     gaps = reduce.idle_gaps(tr, lo, hi, ignore=("bench.traced",))

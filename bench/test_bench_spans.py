@@ -76,6 +76,31 @@ def test_idle_split_partitions_idle_time():
     assert m["plan_ms_per_read"] == pytest.approx((30 + 40) / 2)
 
 
+def test_span_totals_per_tick():
+    """Every serving-thread span's inclusive time and count per tick, over
+    the spans that begin in the window: the client's ``engine.combine`` and
+    the eager calls are not among them.  ``planner.compile_plan``'s time
+    over its count is ``plan_ms_per_read``."""
+    m = spans.reduce_spans(_xspace(), ticks=2)["metrics"]
+    ms = {"server.tick": 400, "planner.compile_plan": 30 + 40,
+          "engine.execute_many": 200, "engine.scan_multi": 100,
+          "server.finish_tick": 470, "server.finalize": 60}
+    count = dict.fromkeys(ms, 1) | {"planner.compile_plan": 2}
+    assert m["span_ms_per_tick"] == pytest.approx(
+        {k: v / 2 for k, v in ms.items()})
+    assert m["span_count_per_tick"] == {k: v / 2 for k, v in count.items()}
+    plan = "planner.compile_plan"
+    assert (m["span_ms_per_tick"][plan] / m["span_count_per_tick"][plan]
+            == pytest.approx(m["plan_ms_per_read"], rel=1e-12))
+    # only the spans that begin in the window count, each with its whole time
+    m = spans.metrics(spans.serving_thread(_xspace()).spans.spans, [],
+                      0.0, 100 * MS, 1)
+    assert m["span_count_per_tick"] == {"server.tick": 1,
+                                        "planner.compile_plan": 2}
+    assert m["span_ms_per_tick"] == pytest.approx(
+        {"server.tick": 400, "planner.compile_plan": 70})
+
+
 def test_idle_metrics_sum_to_the_idle_share():
     """The three idle metrics add up to ``device_idle_share`` / 100 x the
     traced window / ticks, as ``bench/metrics`` reads the same trace."""
@@ -202,10 +227,19 @@ def test_recorded_chip_trace_with_spans():
     assert m["idle_between_ticks_ms_per_tick"] == pytest.approx(
         2.0627733333333333)
     assert m["plan_ms_per_read"] == pytest.approx(0.09259454166666665)
+    plan = "planner.compile_plan"
+    assert m["span_count_per_tick"][plan] == 8
+    assert (m["span_ms_per_tick"][plan] / m["span_count_per_tick"][plan]
+            == pytest.approx(m["plan_ms_per_read"], rel=1e-12))
+    assert m["span_count_per_tick"]["engine.serve_scan"] == 1
+    assert m["span_ms_per_tick"]["server.tick"] > m["span_ms_per_tick"][
+        "engine.execute_many"] > m["span_ms_per_tick"]["engine.serve_scan"]
     tr = reduce.load_trace(xs)
     window = tr.annotation("bench.traced")
     lo, hi = window.start_ns, window.end_ns
     dt = reduce.device_time(tr, lo, hi, chips=1)
+    assert dt.busy_ns_per_chip == [dt.busy_ns]
+    assert dt.kernel_ns_per_chip == [dt.kernel_ns]
     share = 100.0 * (1.0 - dt.busy_ns / dt.window_ns)  # device_idle_share
     idle = sum(v for k, v in m.items() if k.startswith("idle_"))
     assert idle == pytest.approx(share / 100 * dt.window_ns * 1e-6 / 3,
@@ -260,6 +294,9 @@ def test_span_metrics_reach_the_readers():
     assert _read("idle_between_ticks_ms_per_tick", w) == pytest.approx(
         130 / 2)
     assert _read("plan_ms_per_read", w) == pytest.approx((30 + 40) / 2)
+    assert w.traced.spans["span_ms_per_tick"][
+        "planner.compile_plan"] == pytest.approx((30 + 40) / 2)
+    assert w.traced.spans["span_count_per_tick"]["engine.scan_multi"] == 0.5
     idle = sum(_read(f"idle_{k}_ms_per_tick", w)
                for k in ("dispatch", "frontend", "between_ticks"))
     share = _read("device_idle_share", w)
@@ -284,6 +321,11 @@ def test_hbm_roofline_over_the_chips(chips):
     need = 2 * (PROBE_ROWS * 4 * 2 + BUILD_ROWS * 4 * 1 + 100)
     assert w.traced.need_bytes == need
     assert w.traced.device.busy_ns == pytest.approx(500 * MS)
+    per_chip = w.traced.device.busy_ns_per_chip
+    assert len(per_chip) == chips
+    assert sum(per_chip) / chips == w.traced.device.busy_ns
+    assert sum(w.traced.device.kernel_ns_per_chip) / chips == (
+        w.traced.device.kernel_ns)
     assert w.traced.hbm_bytes_per_s == chips * 819e9
     want = 100.0 * need / (chips * 819e9) / 0.5
     assert _read("hbm_roofline", w) == pytest.approx(want, rel=1e-12)
@@ -311,6 +353,37 @@ def test_root_chip_is_read_by_name(chips, root_last):
     assert m["idle_dispatch_ms_per_tick"] == pytest.approx(100 / 2)
     assert m["idle_frontend_ms_per_tick"] == pytest.approx(270 / 2)
     assert m["idle_between_ticks_ms_per_tick"] == pytest.approx(130 / 2)
+    # each chip's busy time: chip 0's first, then in device order
+    dt = reduce.device_time(tr, lo, hi, chips)
+    others = 550 * MS if root_last else 500 * MS
+    assert dt.busy_ns_per_chip == [500 * MS] + [others] * (chips - 1)
+    assert dt.kernel_ns_per_chip == [300 * MS] * chips
+    assert sum(dt.busy_ns_per_chip) / chips == pytest.approx(dt.busy_ns,
+                                                             rel=1e-15)
+
+
+@pytest.mark.parametrize("chips", [1, 2])
+def test_only_the_cells_chips_are_read(chips):
+    """A cell of ``chips`` chips on a host of four, whose trace lists all
+    four planes from chip 3 down: the per-chip times are those of chips
+    0 to ``chips - 1`` alone, in device order, and their mean is
+    ``busy_ns``.  Chips 1-3 are busy 550 ms, chip 0 500 ms."""
+    tr = reduce.load_trace(_xspace(4, root_last=True))
+    dt = reduce.device_time(tr, 0.0, 1000 * MS, chips)
+    assert dt.busy_ns_per_chip == [500 * MS, 550 * MS][:chips]
+    assert dt.kernel_ns_per_chip == [300 * MS] * chips
+    assert dt.busy_ns == sum(dt.busy_ns_per_chip) / chips
+    assert dt.glue_ns == pytest.approx(
+        (200 * MS + 250 * MS * (chips - 1)) / chips)
+
+
+def test_trace_without_a_cells_chip_is_refused():
+    xs = _xspace(4)
+    del xs.planes[1]  # chip 1's plane
+    tr = reduce.load_trace(xs)
+    assert reduce.device_time(tr, 0.0, 1000 * MS, 1).busy_ns == 500 * MS
+    with pytest.raises(ValueError, match="TPU:1"):
+        reduce.device_time(tr, 0.0, 1000 * MS, 2)
 
 
 def test_trace_without_the_root_plane_is_refused():
@@ -321,3 +394,5 @@ def test_trace_without_the_root_plane_is_refused():
         reduce.idle_gaps(tr, 0.0, 1000 * MS)
     with pytest.raises(ValueError, match="TPU:0"):
         spans.busy_intervals(tr, 0.0, 1000 * MS)
+    with pytest.raises(ValueError, match="TPU:0"):
+        reduce.device_time(tr, 0.0, 1000 * MS, 2)
