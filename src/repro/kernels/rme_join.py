@@ -21,16 +21,17 @@ module moves the probe itself next to the data:
 
 TPU adaptation: buckets are selected with a one-hot MXU contraction (the
 ``groupby_sum`` idiom), not a gather.  Every int32 bucket word also travels
-as four **byte planes** — byte ``j`` of each word, 0..255, exact in bfloat16's
-8 significant bits — laid side by side in one ``(P, K)`` bfloat16 array per
-pair of columns (``kv_planes``: key and payload; ``ts_planes``: begin and
-end).  The one-hot is exactly 0 or 1, so a single default-precision bf16 ×
-bf16 → float32 pass selects every byte exactly (one nonzero term per output,
-at most 255); a second, eight times smaller pass pairs the bytes into exact
-16-bit halves on whole 128-lane tiles, and shifts and ORs rebuild the
-original bit pattern — bit-exact selection at the MXU's single-pass rate, no
-dynamic indexing in the kernel.  ``K`` is ``8·C`` padded to whole 128-lane
-tiles: it follows the observed capacity.
+as four **int8 byte planes** — byte ``j`` of each word, its raw bits read as
+two's complement — laid side by side in one ``(P, K)`` int8 array per pair
+of columns (``kv_planes``: key and payload; ``ts_planes``: begin and end).
+The one-hot is exactly 0 or 1, so a single int8 × int8 → int32 pass selects
+every byte exactly (one nonzero term per output) at the MXU's integer rate,
+twice its bfloat16 rate; ``& 0xFF`` on the VPU recovers ``0..255``.  A
+second, eight times smaller bfloat16 pass (``0..255`` is exact in bfloat16's
+8 significant bits) pairs the bytes into exact 16-bit halves on whole
+128-lane tiles, and shifts and ORs rebuild the original bit pattern —
+bit-exact selection, no dynamic indexing in the kernel.  ``K`` is ``8·C``
+padded to whole 128-lane tiles: it follows the observed capacity.
 
 The bucket hash is **Fibonacci multiplicative hashing**: ``bucket = (key *
 2654435761) >>> (32 - log2 P)`` (the top bits of the wrapped product, same
@@ -83,7 +84,7 @@ MIX_INT32 = np.int32(np.uint32(2654435761).astype(np.int64) - (1 << 32))
 
 class JoinPartitions(NamedTuple):
     """The build side as static device buckets: four ``(P, C)`` int32 arrays
-    and their byte planes, two ``(P, K)`` bfloat16 arrays.
+    and their byte planes, two ``(P, K)`` int8 arrays (1 B a lane).
 
     A NamedTuple of arrays on purpose — the planner's join build cache
     accounts entry bytes by iterating the entry, exactly as it does for the
@@ -98,8 +99,8 @@ class JoinPartitions(NamedTuple):
     vals: jax.Array  # (P, C) raw int32 payload words
     begin: jax.Array  # (P, C) __ts_begin of each build row
     end: jax.Array  # (P, C) __ts_end of each build row
-    kv_planes: jax.Array  # (P, K) bf16 bytes 0..3 of keys, then of vals
-    ts_planes: jax.Array  # (P, K) bf16 bytes 0..3 of begin, then of end
+    kv_planes: jax.Array  # (P, K) int8 bytes 0..3 of keys, then of vals
+    ts_planes: jax.Array  # (P, K) int8 bytes 0..3 of begin, then of end
 
     @property
     def num_buckets(self) -> int:
@@ -161,25 +162,25 @@ def plane_lanes(capacity: int) -> int:
 
 def estimated_partition_bytes(n_rows: int) -> int:
     """Planner-side estimate of a build table's partition-array bytes (four
-    ``(P, C)`` int32 arrays and two ``(P, K)`` bfloat16 plane arrays at the
+    ``(P, C)`` int32 arrays and two ``(P, K)`` int8 plane arrays at the
     target load) — the build-upload term of the join route cost model,
     available before anything is built."""
     p = num_buckets_for(n_rows)
     c = max(1, -(-n_rows // p))
-    return 4 * p * c * 4 + 2 * p * plane_lanes(c) * 2
+    return 4 * p * c * 4 + 2 * p * plane_lanes(c)
 
 
 def _byte_planes(a: np.ndarray, b: np.ndarray) -> jax.Array:
-    """Two ``(P, C)`` int32 columns as one ``(P, K)`` bfloat16 array: lanes
+    """Two ``(P, C)`` int32 columns as one ``(P, K)`` int8 array: lanes
     ``[q·C, (q+1)·C)`` hold plane ``q = 4w + j``, byte ``j`` of column
-    ``w``."""
+    ``w``, its raw bits read as two's complement."""
     p, c = a.shape
     u = np.stack([a, b]).view(np.uint32)[:, None]  # (2, 1, P, C)
     shifts = np.arange(0, 32, 8, dtype=np.uint32)[None, :, None, None]
-    planes = (u >> shifts) & np.uint32(0xFF)  # (2, 4, P, C)
-    out = np.zeros((p, plane_lanes(c)), np.float32)
+    planes = ((u >> shifts) & np.uint32(0xFF)).astype(np.uint8)  # (2, 4, P, C)
+    out = np.zeros((p, plane_lanes(c)), np.uint8)
     out[:, : 8 * c] = planes.transpose(2, 0, 1, 3).reshape(p, 8 * c)
-    return jnp.asarray(out.astype(jnp.bfloat16))
+    return jnp.asarray(out.view(np.int8))
 
 
 @functools.lru_cache(maxsize=None)
@@ -251,17 +252,18 @@ def build_partitions(
 # ------------------------------------------------------------ Pallas probe
 def _select_words(onehot: jax.Array, planes: jax.Array,
                   weights: jax.Array) -> tuple[jax.Array, jax.Array]:
-    """Bit-exact per-row bucket selection on the MXU, two bfloat16 passes
-    with float32 results: ``(B, P) @ (P, K)`` selects every byte (one
-    nonzero term per output, at most 255), then ``(B, K) @ (K, 4·Cp)`` pairs
-    the bytes into 16-bit halves (``b_lo + 256·b_hi < 2^16``, one or two
-    nonzero terms).  Shifts and ORs rebuild both columns' int32 words,
-    ``(B, Cp)`` each; slots at and above ``C`` read 0.
+    """Bit-exact per-row bucket selection on the MXU: an int8 pass with
+    int32 results, ``(B, P) @ (P, K)``, selects every byte (one nonzero
+    term per output, the stored byte as two's complement; ``& 0xFF`` gives
+    ``0..255``), then a bfloat16 pass with float32 results, ``(B, K) @ (K,
+    4·Cp)``, pairs the bytes into 16-bit halves (``b_lo + 256·b_hi <
+    2^16``, one or two nonzero terms).  Shifts and ORs rebuild both
+    columns' int32 words, ``(B, Cp)`` each; slots at and above ``C`` read 0.
 
     The halves land on whole 128-lane tiles on purpose: slicing bytes at
     unaligned lane offsets and shifting them into place compiled to wrong
     bits on a v5e, while the pairing pass is exact there."""
-    sel = jnp.dot(onehot, planes, preferred_element_type=jnp.float32)
+    sel = jnp.dot(onehot, planes, preferred_element_type=jnp.int32) & 0xFF
     halves = jnp.dot(sel.astype(jnp.bfloat16), weights,
                      preferred_element_type=jnp.float32)
     cp = halves.shape[1] // 4
@@ -280,7 +282,7 @@ def _probe_kernel(key_word, val_word, ts_word, build_ts, capacity, n_rows,
     g = _bucket_of(s_key, p)
     onehot = (
         g[:, None] == jax.lax.iota(jnp.int32, p)[None, :]
-    ).astype(jnp.bfloat16)  # (B, P), exact
+    ).astype(jnp.int8)  # (B, P), exact
     weights = w_ref[...]
     keys, vals = _select_words(onehot, kv_ref[...], weights)  # (B, Cp) each
     # padding slots read key 0, a word a probe row may hold
@@ -445,10 +447,10 @@ def probe_vmem_footprint_bytes(
 
     Every VMEM array is tiled ``(8, 128)``, so narrow minor dimensions are
     charged at 128 lanes: the double-buffered row tile and three ``(B, 1)``
-    output columns, the double-buffered ``(P, K)`` bfloat16 plane arrays
-    (two with ``build_ts``) and ``(K, 4·Cp)`` pairing weights, the int32
-    compare and bfloat16 one-hot ``(B, P)``, and per plane array the
-    float32 ``(B, K)`` bytes, their bfloat16 copy and the float32 ``(B,
+    output columns, the double-buffered ``(P, K)`` int8 plane arrays (two
+    with ``build_ts``) and bfloat16 ``(K, 4·Cp)`` pairing weights, the int32
+    compare and int8 one-hot ``(B, P)``, and per plane array the int32
+    ``(B, K)`` selected bytes, their bfloat16 copy and the float32 ``(B,
     4·Cp)`` halves.  An upper bound of what Mosaic allocates, checked
     against compiles for a v5e in ``tests/test_tpu_compile.py``.
     """
@@ -458,8 +460,8 @@ def probe_vmem_footprint_bytes(
     k = partitions.kv_planes.shape[1]
     halves = 4 * _pad(partitions.capacity, 128)
     tiles = 2 * b * (_pad(row_words, 128) + 3 * 128) * 4
-    planes = 2 * (n_planes * p * k + k * halves) * 2
-    onehot = b * p * (4 + 2)
+    planes = 2 * (n_planes * p * k * 1 + k * halves * 2)
+    onehot = b * p * (4 + 1)
     selected = n_planes * b * (k * (4 + 2) + halves * 4)
     return tiles + planes + onehot + selected
 

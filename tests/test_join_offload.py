@@ -137,9 +137,12 @@ def test_partition_invariants():
 
 I32 = np.iinfo(np.int32)
 # words whose bytes cover every edge: sign bit, all ones, zero, alternating
-# bytes, and values above 2^24 that a float32 cannot hold
+# bytes, values above 2^24 that a float32 cannot hold, and bytes on the int8
+# planes' sign boundary (0x80808080, 0x7F7F7F7F, 0x807F807F, 0x7F807F80)
 EDGE_WORDS = np.array([I32.min, I32.max, -1, 0, 1, 0x00FF00FF, -0x00FF0100,
-                       (1 << 24) + 1, -(1 << 24) - 3, 0x7F00FF01], np.int32)
+                       (1 << 24) + 1, -(1 << 24) - 3, 0x7F00FF01,
+                       -2139062144, 2139062143, -2139127681, 2139127680],
+                      np.int32)
 EXACT_CASES = [("edges", False), ("edges", True), ("fills", False),
                ("fills", True), ("wide", False), ("wide", True),
                ("versions", True)]
@@ -181,7 +184,7 @@ def _exact_build_side(case, rng):
                          ids=[f"{c}-{'ts' if t else 'nots'}"
                               for c, t in EXACT_CASES])
 def test_byte_plane_probe_is_bit_exact(case, build_ts):
-    """The Pallas probe selects bucket words through bfloat16 byte planes:
+    """The Pallas probe selects bucket words through int8 byte planes:
     every int32 key, payload and timestamp word must come back bit-exact —
     edge words, words above 2^24, the bucket fills, and a crowded bucket
     whose 8·C planes span more than one 128-lane tile."""
@@ -191,8 +194,8 @@ def test_byte_plane_probe_is_bit_exact(case, build_ts):
     p, c = parts.num_buckets, parts.capacity
     k = KJ.plane_lanes(c)
     assert parts.kv_planes.shape == parts.ts_planes.shape == (p, k)
-    assert parts.kv_planes.dtype == jnp.bfloat16
-    assert parts.nbytes == 4 * p * c * 4 + 2 * p * k * 2  # planes counted
+    assert parts.kv_planes.dtype == jnp.int8
+    assert parts.nbytes == 4 * p * c * 4 + 2 * p * k * 1  # planes counted
     assert parts.nbytes <= 8 * KJ.estimated_partition_bytes(key.shape[0])
     if case == "wide":
         assert c > 16 and k > 128
@@ -507,22 +510,23 @@ def test_probe_too_large_for_vmem_takes_counted_xla_route(
 
 def test_probe_vmem_guard_on_v5e():
     """On a v5e's budget the bench-scale build side (P = 4096) probes at the
-    full row tile, pinned or not; a 1M-row build side (P = 65536) probes at
-    a quarter tile unpinned and fits no tile under a snapshot, whose second
-    plane array it cannot hold."""
+    full row tile, pinned or not; a 1M-row build side (P = 65536), whose
+    int8 plane arrays take 16 MiB each, probes at half the tile unpinned and
+    at a quarter under a snapshot, which holds a second plane array."""
     limit = common.vmem_limit_bytes("TPU v5 lite")
     eng = RelationalMemoryEngine()
 
     def parts(p):
         z = jnp.zeros((p, 19), jnp.int32)
-        planes = jnp.zeros((p, KJ.plane_lanes(19)), jnp.bfloat16)
+        planes = jnp.zeros((p, KJ.plane_lanes(19)), jnp.int8)
         return KJ.JoinPartitions(z, z, z, z, planes, planes)
 
     for build_ts in (False, True):
         assert eng._probe_block_rows(parts(4096), 18, limit,
                                      build_ts) == eng.block_rows
-    assert eng._probe_block_rows(parts(65536), 18, limit) == eng.block_rows // 4
-    assert eng._probe_block_rows(parts(65536), 18, limit, True) is None
+    assert eng._probe_block_rows(parts(65536), 18, limit) == eng.block_rows // 2
+    assert eng._probe_block_rows(parts(65536), 18, limit,
+                                 True) == eng.block_rows // 4
     with pytest.raises(ValueError, match="unknown TPU device kind"):
         common.vmem_limit_bytes("TPU v99")
 

@@ -160,7 +160,7 @@ def _partitions(sharding, buckets, capacity):
     """Shapes of a built ``JoinPartitions`` at ``(buckets, capacity)``."""
     bucket = _shape(sharding, buckets, capacity)
     planes = jax.ShapeDtypeStruct((buckets, KJ.plane_lanes(capacity)),
-                                  jnp.bfloat16, sharding=sharding)
+                                  jnp.int8, sharding=sharding)
     return KJ.JoinPartitions(bucket, bucket, bucket, bucket, planes, planes)
 
 
